@@ -429,7 +429,6 @@ def minibatch_shard():
     """
     import jax
     import jax.numpy as jnp
-    from repro import compat  # noqa: F401  (make_mesh shim)
     from repro import core
     from repro.core.engine import ClusteringEngine, EngineConfig
 
@@ -527,15 +526,10 @@ def sharded_overlap():
     import subprocess
     import sys
     import tempfile
-    import jax
     from repro.launch.mesh import overlap_env
 
-    if len(jax.devices()) < 8:
-        print("# sharded_overlap: needs 8 devices (set XLA_FLAGS="
-              "--xla_force_host_platform_device_count=8); skipping — NOT "
-              "writing BENCH_sharded_overlap.json")
-        return []
-
+    # this process stays off JAX: on an accelerator the device belongs to
+    # the process that touches it first, and every worker needs it
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     legs = {}
     for leg, enable in (("sync", False), ("overlap", True)):
@@ -544,11 +538,18 @@ def sharded_overlap():
         cmd = [sys.executable, "-m", "benchmarks.sharded_overlap_worker",
                "--out", out, "--leg", leg] + (["--prefetch"] if enable
                                               else [])
+        # the device counts are host-platform emulation: pin the CPU
         subprocess.run(cmd, check=True, cwd=root,
-                       env=overlap_env(enable=enable))
+                       env=overlap_env({**os.environ, "JAX_PLATFORMS": "cpu"},
+                                       enable=enable, platform="cpu"))
         with open(out) as f:
             legs[leg] = json.load(f)
         os.unlink(out)
+        if legs[leg]["visible_devices"] < 8:
+            print("# sharded_overlap: needs 8 devices (set XLA_FLAGS="
+                  "--xla_force_host_platform_device_count=8); skipping — "
+                  "NOT writing BENCH_sharded_overlap.json")
+            return []
 
     rows = [r for leg in ("sync", "overlap") for r in legs[leg]["rows"]]
     cell = {(r["leg"], r["devices"], r["compression"]): r for r in rows}
@@ -574,8 +575,10 @@ def sharded_overlap():
         "timed_wall_s_total": wall,
         "claims": {
             "int8_parity_delta_le_1": bool(max(parity.values()) <= 1),
-            "wire_byte_reduction_ge_3x":
-                bool(min(byte_ratio.values()) >= 3.0),
+            # int8 payload + one f32 scale per cluster row + exact
+            # counts: ~(d+1)·4 / (d+8) of the fp32 bytes, 2.2x at d=8
+            "wire_byte_reduction_ge_2x":
+                bool(min(byte_ratio.values()) >= 2.0),
             "overlap_wall_no_worse_1p15x":
                 bool(wall["overlap"] <= wall["sync"] * 1.15),
         },
@@ -610,7 +613,6 @@ def kernel_backends():
     """
     import jax
     import jax.numpy as jnp
-    from repro import compat  # noqa: F401  (make_mesh shim)
     from repro import core
     from repro.core.engine import ClusteringEngine, EngineConfig
 

@@ -42,7 +42,6 @@ def main():
 
     import jax
     import jax.numpy as jnp
-    from repro import compat  # noqa: F401  (shard_map / make_mesh shims)
     from repro import core
     from repro.core.engine import (ClusteringEngine, EngineConfig,
                                    get_algorithm, stats_wire_bytes)

@@ -265,14 +265,15 @@ class _UniformWalker:
 
     def _shard_map(self, eqn, path):
         p = eqn.params
-        in_names = p.get("in_names")
+        in_specs = p.get("in_specs")
         inner = p["jaxpr"]
         n_in = len(as_open(inner).invars)
-        if in_names is None:
+        if in_specs is None:
             ins = [False] * n_in
         else:
-            # {} = replicated operand → uniform; any named axis → sharded
-            ins = [not dict(names) for names in in_names]
+            # a spec naming no mesh axis = replicated operand → uniform;
+            # any named axis → sharded
+            ins = [all(axes is None for axes in spec) for spec in in_specs]
             ins += [False] * (n_in - len(ins))
         outs = self.run(inner, ins, path)
         n = len(eqn.outvars)

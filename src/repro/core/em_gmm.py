@@ -27,6 +27,8 @@ class GMMParams(NamedTuple):
 
 
 VAR_FLOOR = 1e-6
+# f32 matmuls: the TPU's default precision multiplies in bf16
+_HI = jax.lax.Precision.HIGHEST
 
 
 def log_prob(x, params: GMMParams):
@@ -34,8 +36,8 @@ def log_prob(x, params: GMMParams):
     x = x.astype(jnp.float32)
     inv_var = 1.0 / params.var                                   # [K,D]
     # Σ_d (x−μ)²/σ² = x²·(1/σ²) − 2·x·(μ/σ²) + Σ_d μ²/σ²
-    quad = ((x * x) @ inv_var.T
-            - 2.0 * (x @ (params.means * inv_var).T)
+    quad = (jnp.matmul(x * x, inv_var.T, precision=_HI)
+            - 2.0 * jnp.matmul(x, (params.means * inv_var).T, precision=_HI)
             + jnp.sum(params.means ** 2 * inv_var, axis=-1)[None, :])
     log_det = jnp.sum(jnp.log(params.var), axis=-1)              # [K]
     d = x.shape[-1]
@@ -73,8 +75,8 @@ def estep_stats(x, params: GMMParams, axis_name=None, use_kernel: bool = False,
             loglik = jnp.sum(lse)
         r_sum = jnp.sum(resp, axis=0)                            # [K]
         xf = x.astype(jnp.float32)
-        r_x = resp.T @ xf                                        # [K,D]
-        r_x2 = resp.T @ (xf * xf)                                # [K,D]
+        r_x = jnp.matmul(resp.T, xf, precision=_HI)              # [K,D]
+        r_x2 = jnp.matmul(resp.T, xf * xf, precision=_HI)        # [K,D]
     if axis_name is not None:
         loglik = jax.lax.psum(loglik, axis_name)
         r_sum = jax.lax.psum(r_sum, axis_name)

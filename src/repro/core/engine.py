@@ -228,23 +228,31 @@ class EMAlgorithm:
     # counterpart).  EM *requires* this: the M-step variance is
     # r_x2/r_sum − mean², a catastrophic cancellation — with means ~9 and
     # var ~1 the raw second moment is ~82 while the variance is 1, so a 1%
-    # int8 error on r_x2 is an ~80% error on var and EM diverges.  Centred
-    # moments Σr(x−μ) and Σr(x−μ)² are the same magnitude as the answers
-    # they produce, so quantisation error stays proportional.  Both
-    # transforms are linear in (r_sum, r_x, r_x2) per shard, commute with
-    # the cross-shard sum, and invert exactly from the reduced tree.
+    # int8 error on r_x2 is an ~80% error on var and EM diverges.  The
+    # first moment is centred on the current means, Σr(x−μ), and the
+    # second on the current means and variances, Σr((x−μ)² − σ²): both
+    # shrink to the parameter motion as the fit converges, so the int8
+    # scale and its rounding shrink with them (centring the second moment
+    # on μ alone left it at r·σ², and the variance rounding kept EM's
+    # loglik jittering above the stop threshold).  The transforms are
+    # linear per shard, commute with the cross-shard sum, and invert
+    # exactly with the reduced r_sum (a vector leaf, reduced exact).
+    # Still open: a dimension whose variance collapses towards the floor
+    # while others in its cluster row move is rounded on the row's scale.
     def compress_basis(self, params, stats):
         r_sum, r_x, r_x2, ll = stats
-        a = params.means
+        a, v = params.means, params.var
         r_xc = r_x - r_sum[:, None] * a
-        r_x2c = r_x2 - 2.0 * a * r_x + (a * a) * r_sum[:, None]
+        r_x2c = (r_x2 - 2.0 * a * r_x + (a * a) * r_sum[:, None]
+                 - r_sum[:, None] * v)
         return (r_sum, r_xc, r_x2c, ll)
 
     def decompress_basis(self, params, stats):
         r_sum, r_xc, r_x2c, ll = stats
-        a = params.means
+        a, v = params.means, params.var
         r_x = r_xc + r_sum[:, None] * a
-        r_x2 = r_x2c + 2.0 * a * r_x - (a * a) * r_sum[:, None]
+        r_x2 = (r_x2c + r_sum[:, None] * v + 2.0 * a * r_x
+                - (a * a) * r_sum[:, None])
         return (r_sum, r_x, r_x2, ll)
 
     def moved(self, new_params, params):
@@ -371,9 +379,15 @@ class EngineConfig:
         # engine config through the kernel dispatch layer, so the whole
         # engine suite doubles as kernel-path coverage.  An explicitly
         # pinned kernel_backend wins over the env (backend-vs-backend
-        # parity tests keep comparing what they name).
+        # parity tests keep comparing what they name).  It is a CPU test
+        # hook: on a TPU it would reroute fits off the compiled kernel.
         forced = os.environ.get("REPRO_FORCE_KERNEL_BACKEND")
         if forced:
+            if jax.default_backend() == "tpu":
+                raise RuntimeError(
+                    "REPRO_FORCE_KERNEL_BACKEND is a CPU test hook; unset "
+                    "it on a TPU, where use_kernel resolves to the "
+                    "compiled 'tpu' kernel")
             if not self.use_kernel:
                 object.__setattr__(self, "use_kernel", True)
             if self.kernel_backend in (None, "auto"):
@@ -462,9 +476,11 @@ class EngineConfig:
 
     # engine-regime fields a fitted LongTailModel's provenance is compared
     # against in from_longtail (chunks only matters when minibatch draws
-    # sample from it — full-mode chunking is a memory layout, not a regime)
-    MATCHED_FIELDS = ("mode", "batch_chunks", "decay", "ema", "use_kernel",
-                      "kernel_backend")
+    # sample from it — full-mode chunking is a memory layout, not a regime).
+    # kernel_backend is not one: tpu, interpret and xla run the same kernel
+    # math, so a model fitted with use_kernel on one platform is matched on
+    # another, and the serving config resolves the backend where it runs.
+    MATCHED_FIELDS = ("mode", "batch_chunks", "decay", "ema", "use_kernel")
 
     def matched_fingerprint(self) -> dict:
         """The regime this config clusters under, as stampable provenance."""
@@ -604,11 +620,14 @@ def _stats_reducer(alg, config: EngineConfig):
     moments taken around the current parameters, so the transmitted values
     shrink as the fit converges and the pmax-shared int8 scale shrinks with
     them; for EM this is what makes compression viable at all, see
-    ``EMAlgorithm.compress_basis``).  Array-valued leaves (ndim >= 1) then
-    go through ``compress_with_feedback`` + ``ring_allreduce_int8`` (sum
-    mode, int8 on the wire, Karimireddy-style residual carried to the next
-    iteration) while the scalar leaves (J / loglik) stay exact fp32 psum —
-    they drive the Eq. 7 stop, where int8's ~8e-3 relative resolution is
+    ``EMAlgorithm.compress_basis``).  Matrix leaves (ndim >= 2: the [K, D]
+    moments) then go through ``compress_with_feedback`` +
+    ``ring_allreduce_int8`` (sum mode, int8 on the wire with one scale
+    per cluster row, Karimireddy-style residual carried to the next
+    iteration) while the vector leaves (counts / r_sum, K floats — the
+    basis inverts exactly with them) and the scalar leaves (J / loglik)
+    stay exact fp32 psum — the scalars drive the Eq. 7 stop, where int8's
+    ~8e-3 relative resolution is
     orders of magnitude above production h* thresholds.  The reduced tree
     is rotated back via ``alg.decompress_basis`` (an exact linear
     inversion using the reduced tree itself).
@@ -638,14 +657,14 @@ def _stats_reducer(alg, config: EngineConfig):
         """Zero residual buffers for the compressed (ndim >= 1) leaves."""
         return tuple(jnp.zeros(jnp.shape(a), jnp.float32)
                      for a in jax.tree.leaves(stats_like)
-                     if jnp.ndim(a) >= 1)
+                     if jnp.ndim(a) >= 2)
 
     def reduce_stats(stats, ef, params):
         stats = alg.compress_basis(params, stats)
         flat, tree = jax.tree.flatten(stats)
         out, new_ef, i = [], [], 0
         for a in flat:
-            if jnp.ndim(a) == 0:
+            if jnp.ndim(a) < 2:
                 out.append(jax.lax.psum(a, axis))
                 continue
             reduced, e = compress_with_feedback(
@@ -666,9 +685,9 @@ def stats_wire_bytes(stats_like, axis_size: int,
     """Analytic bytes-on-wire each device sends for ONE stats reduction.
 
     Mirrors ``_stats_reducer``'s leaf policy: under ``int8_ef`` every
-    ndim >= 1 leaf moves 1 byte/element over the ring plus one f32 scalar
-    pmax for its shared scale; scalar leaves (and every leaf under
-    ``none``) move 4 bytes/element.  Both paths carry the same ring factor
+    matrix leaf (ndim >= 2) moves 1 byte/element over the ring plus the
+    f32 pmax of its shared scales, one per row; vector and scalar leaves
+    (and every leaf under ``none``) move 4 bytes/element.  Both paths carry the same ring factor
     2·(N−1)/N, so it cancels in int8-vs-fp32 ratios but keeps the absolute
     numbers meaningful to a cost model.  ``stats_like`` may be concrete or
     abstract (``jax.eval_shape``) — only shapes are read.
@@ -680,9 +699,9 @@ def stats_wire_bytes(stats_like, axis_size: int,
         n = 1
         for s in shape:
             n *= int(s)
-        if compression == "int8_ef" and len(shape) >= 1:
-            total += ring_wire_bytes(n, axis_size)       # int8 payload
-            total += ring_wire_bytes(4, axis_size)       # f32 scale pmax
+        if compression == "int8_ef" and len(shape) >= 2:
+            total += ring_wire_bytes(n, axis_size)              # int8
+            total += ring_wire_bytes(4 * int(shape[0]), axis_size)  # scales
         else:
             total += ring_wire_bytes(4 * n, axis_size)   # fp32 psum
     return total

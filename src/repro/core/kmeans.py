@@ -46,7 +46,9 @@ def assign_and_stats(x, centroids, axis_name=None, use_kernel: bool = False,
         c = centroids.astype(jnp.float32)
         x2 = jnp.sum(x * x, axis=-1, keepdims=True)          # [N,1]
         c2 = jnp.sum(c * c, axis=-1)                         # [K]
-        d2 = x2 - 2.0 * (x @ c.T) + c2[None, :]              # [N,K] (MXU matmul)
+        # [N,K] MXU matmul, f32: the TPU's default precision is bf16
+        d2 = (x2 - 2.0 * jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
+              + c2[None, :])
         labels = jnp.argmin(d2, axis=-1).astype(jnp.int32)
         mind2 = jnp.maximum(jnp.min(d2, axis=-1), 0.0)       # clamp fp cancellation
         k = centroids.shape[0]

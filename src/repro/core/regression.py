@@ -21,6 +21,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# h spans decades down to ~1e-6: the fits and predictions need f32
+# matmuls, and the TPU's default precision multiplies in bf16
+_HI = jax.lax.Precision.HIGHEST
+
 FAMILIES = ("linear", "quadratic", "cubic", "exponential", "lasso_quadratic",
             "log_quadratic")
 
@@ -46,7 +50,7 @@ class RegressionModel:
         if self.family in ("linear", "quadratic", "cubic", "lasso_quadratic"):
             # coeffs = (β₀, β₁, …) low-to-high degree
             powers = jnp.stack([r ** p for p in range(len(self.coeffs))], axis=-1)
-            return powers @ c
+            return jnp.matmul(powers, c, precision=_HI)
         if self.family == "exponential":
             a, b = self.coeffs
             return a * jnp.exp(b * r)
@@ -87,7 +91,8 @@ def _metrics(h: jnp.ndarray, pred: jnp.ndarray, n_params: int) -> FitMetrics:
 def _polyfit(r: jnp.ndarray, h: jnp.ndarray, degree: int) -> jnp.ndarray:
     """Least-squares polynomial fit via QR on the Vandermonde matrix."""
     powers = jnp.stack([r ** p for p in range(degree + 1)], axis=-1)
-    coeffs, *_ = jnp.linalg.lstsq(powers, h, rcond=None)
+    with jax.default_matmul_precision("highest"):
+        coeffs, *_ = jnp.linalg.lstsq(powers, h, rcond=None)
     return coeffs
 
 
@@ -99,8 +104,8 @@ def _lasso_quadratic(r: jnp.ndarray, h: jnp.ndarray, lam: float = 1e-4,
 
     def body(_, beta):
         def update(j, b):
-            resid = h - X @ b + X[:, j] * b[j]
-            rho = jnp.dot(X[:, j], resid)
+            resid = h - jnp.matmul(X, b, precision=_HI) + X[:, j] * b[j]
+            rho = jnp.dot(X[:, j], resid, precision=_HI)
             bj = jnp.sign(rho) * jnp.maximum(jnp.abs(rho) - lam, 0.0) / jnp.maximum(col_sq[j], 1e-12)
             return b.at[j].set(bj)
         return jax.lax.fori_loop(0, 3, update, beta)
@@ -139,7 +144,8 @@ def fit_family(r, h, family: str) -> RegressionModel:
         w = (h > eps).astype(h.dtype)
         logh = jnp.log(jnp.maximum(h, eps))
         X = jnp.stack([jnp.ones_like(r), r, r * r], axis=-1) * w[:, None]
-        c, *_ = jnp.linalg.lstsq(X, logh * w, rcond=None)
+        with jax.default_matmul_precision("highest"):
+            c, *_ = jnp.linalg.lstsq(X, logh * w, rcond=None)
     else:
         raise ValueError(f"unknown family {family}")
     coeffs = tuple(float(x) for x in np.asarray(c))

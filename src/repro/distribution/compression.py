@@ -7,8 +7,9 @@ type promotion.  ``ring_allreduce_int8`` is a textbook ring: N−1
 reduce-scatter steps + N−1 all-gather steps via ``lax.ppermute``, moving
 int8 chunks only → 4× collective-byte reduction vs f32 psum (2× vs bf16).
 
-Quantisation: shared per-tensor scale = pmax(|g|)/127 (one scalar pmax —
-negligible), stochastic-free symmetric rounding.  ``ErrorFeedback`` carries
+Quantisation: a shared scale = pmax(|g|)/127, one per row of a matrix leaf
+(per cluster for the engine's [K, D] moments) and one per vector leaf,
+stochastic-free symmetric rounding.  ``ErrorFeedback`` carries
 the per-leaf quantisation residual into the next step (Karimireddy et al.
 2019 — keeps SGD convergence despite biased rounding).
 
@@ -35,8 +36,17 @@ def shared_scale(x, axis_name, axis_size: int = 1):
     """Shared int8 scale covering the worst-case partial SUM (running
     accumulations grow up to axis_size × the per-shard max — scaling by N
     prevents clipping at the cost of proportionally coarser rounding, the
-    inherent precision/size trade of int8 reduction)."""
-    amax = jax.lax.pmax(jnp.max(jnp.abs(x)), axis_name)
+    inherent precision/size trade of int8 reduction).
+
+    A leaf of two or more dims gets one scale per leading index ([R, 1, ...],
+    broadcastable against ``x``), so a row of small values (a cluster that
+    has nearly converged) is not rounded on the step of the widest row."""
+    if x.ndim >= 2:
+        amax = jnp.max(jnp.abs(x), axis=tuple(range(1, x.ndim)),
+                       keepdims=True)
+    else:
+        amax = jnp.max(jnp.abs(x))
+    amax = jax.lax.pmax(amax, axis_name)
     return jnp.maximum(amax * axis_size, 1e-12) / 127.0
 
 
@@ -58,12 +68,19 @@ def ring_allreduce_int8(x, axis_name: str, axis_size: int, *,
     """
     if axis_size == 1:
         return x
-    scale = shared_scale(x, axis_name, axis_size)
     orig_shape = x.shape
     n = x.size
     pad = (-n) % axis_size
-    flat = jnp.concatenate([x.reshape(-1), jnp.zeros((pad,), x.dtype)])
-    chunks = flat.reshape(axis_size, -1)                    # [N, C]
+
+    def chunked(a, fill):                                   # [N, C]
+        flat = jnp.concatenate([a.reshape(-1), jnp.full((pad,), fill,
+                                                        a.dtype)])
+        return flat.reshape(axis_size, -1)
+
+    chunks = chunked(x, 0.0)
+    # per-element view of the (replicated) shared scales, chunked like x
+    scale = chunked(jnp.broadcast_to(shared_scale(x, axis_name, axis_size),
+                                     orig_shape), 1.0)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
 
@@ -74,10 +91,10 @@ def ring_allreduce_int8(x, axis_name: str, axis_size: int, *,
 
     def rs_step(i, acc):
         s = (idx - i) % axis_size               # chunk we finished last step
-        send = quantize_int8(acc[s], scale)     # int8 [C] on the wire
+        send = quantize_int8(acc[s], scale[s])  # int8 [C] on the wire
         recv = jax.lax.ppermute(send, axis_name, perm)
         k = (idx - i - 1) % axis_size           # chunk we accumulate now
-        return acc.at[k].add(dequantize_int8(recv, scale))
+        return acc.at[k].add(dequantize_int8(recv, scale[k]))
 
     acc = jax.lax.fori_loop(0, axis_size - 1, rs_step, acc)
 
@@ -86,15 +103,15 @@ def ring_allreduce_int8(x, axis_name: str, axis_size: int, *,
     # keeps the same quantise→dequantise round trip its peers see, so the
     # gathered result is bit-identical on every shard.
     own = (idx + 1) % axis_size
-    own_q = quantize_int8(acc[own], scale)      # int8 [C]
+    own_q = quantize_int8(acc[own], scale[own])  # int8 [C]
     out = jnp.zeros_like(chunks)
-    out = out.at[own].set(dequantize_int8(own_q, scale))
+    out = out.at[own].set(dequantize_int8(own_q, scale[own]))
 
     def ag_step(i, carry):
         out, send = carry
         recv = jax.lax.ppermute(send, axis_name, perm)
         k = (idx - i) % axis_size
-        out = out.at[k].set(dequantize_int8(recv, scale))
+        out = out.at[k].set(dequantize_int8(recv, scale[k]))
         return out, recv
 
     out, _ = jax.lax.fori_loop(0, axis_size - 1, ag_step, (out, own_q))

@@ -380,11 +380,6 @@ def sweep_op(op: str, backend: str, *, n: int, k: int, d: int,
 # Roofline peaks (measured on this host, cached per process)
 # --------------------------------------------------------------------------
 
-# nominal fallback ceilings per device kind, used only when measurement
-# is disabled; deliberately conservative
-NOMINAL_PEAKS = {"cpu": (5.0e10, 2.0e10)}
-
-
 @functools.lru_cache(maxsize=None)
 def measure_peaks(kind: str | None = None) -> dict:
     """Achievable peak FLOP/s and HBM bytes/s on this host, via XLA.

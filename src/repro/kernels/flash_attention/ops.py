@@ -77,7 +77,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
     (backend, Sq, Skv, dh) cell; else the hand-picked 128×128 default —
     all capped to the aligned sequence lengths as before.
     """
-    b = dispatch.resolve_backend(backend, interpret)
+    b, fn = OP.impl(backend, interpret)      # unregistered names fail here
     pol = layout.tile_policy(b)
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -89,6 +89,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
     bk = block_k if block_k is not None else (tuned or {}).get("block_k", 128)
     bq = min(bq, round_up(q.shape[2], pol.row_align))
     bk = min(bk, round_up(k.shape[2], pol.row_align))
-    _, fn = OP.impl(b)
     return fn(q, k, v, causal=causal, window=window, scale=float(scale),
               block_q=bq, block_k=bk)

@@ -28,6 +28,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _dot(a, b):
+    """f32 MXU matmul (Mosaic's default contraction precision for f32
+    operands is not guaranteed to be f32)."""
+    return jax.lax.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
 def _kernel(x_ref, w_ref, a_ref, b_ref, const_ref,
             labels_ref, loglik_ref, rsum_ref, rx_ref, rx2_ref,
             *, accumulate: bool):
@@ -41,41 +48,45 @@ def _kernel(x_ref, w_ref, a_ref, b_ref, const_ref,
             rx_ref[...] = jnp.zeros_like(rx_ref)
             rx2_ref[...] = jnp.zeros_like(rx2_ref)
 
+    # 2-D throughout, as in the kmeans_assign kernel: per-point rows
+    # [1, T] with the points on the lanes, per-component columns [K, 1].
     x = x_ref[0].astype(jnp.float32)          # [T, D]
-    w = w_ref[0].astype(jnp.float32)          # [T]
+    w = w_ref[0].astype(jnp.float32)          # [1, T]
     a = a_ref[0]                              # [K, D] = 1/σ²
     b = b_ref[0]                              # [K, D] = μ/σ²
-    const = const_ref[0]                      # [K]
+    const = const_ref[0]                      # [K, 1]
+    k = a.shape[0]
 
     xx = x * x
-    lp = (const[None, :]
-          - 0.5 * jax.lax.dot(xx, a.T, preferred_element_type=jnp.float32)
-          + jax.lax.dot(x, b.T, preferred_element_type=jnp.float32))  # [T,K]
+    xt = x.T                                  # [D, T]
+    lp = (const - 0.5 * _dot(a, xx.T) + _dot(b, xt))        # [K, T]
 
-    m = jnp.max(lp, axis=-1, keepdims=True)                  # online-safe LSE
+    m = jnp.max(lp, axis=0, keepdims=True)                   # online-safe LSE
     e = jnp.exp(lp - m)
-    s = jnp.sum(e, axis=-1, keepdims=True)
-    lse = (m + jnp.log(s))[:, 0]                             # [T]
-    resp = e / s                                             # [T, K]
-    labels = jnp.argmax(lp, axis=-1).astype(jnp.int32)
+    s = jnp.sum(e, axis=0, keepdims=True)
+    lse = m + jnp.log(s)                                     # [1, T]
+    resp = e / s                                             # [K, T]
+    # argmax over K as the first row attaining the maximum
+    rows = jax.lax.broadcasted_iota(jnp.int32, lp.shape, 0)
+    labels = jnp.min(jnp.where(lp == m, rows, k), axis=0, keepdims=True)
     valid = w > 0.0
-    respw = resp * w[:, None]
+    respw = resp * w
 
-    labels_ref[...] = jnp.where(valid, labels, -1)[None]
-    ll_blk = jnp.sum(lse * w)
-    rsum_blk = jnp.sum(respw, axis=0)
-    rx_blk = jax.lax.dot(respw.T, x, preferred_element_type=jnp.float32)
-    rx2_blk = jax.lax.dot(respw.T, xx, preferred_element_type=jnp.float32)
+    labels_ref[0] = jnp.where(valid, labels, -1)
+    ll_blk = jnp.sum(lse * w, axis=1, keepdims=True)         # [1, 1]
+    rsum_blk = jnp.sum(respw, axis=1, keepdims=True)         # [K, 1]
+    rx_blk = _dot(respw, x)                                  # [K, D]
+    rx2_blk = _dot(respw, xx)
     if accumulate:
-        loglik_ref[...] += ll_blk[None, None]
-        rsum_ref[...] += rsum_blk[None]
-        rx_ref[...] += rx_blk[None]
-        rx2_ref[...] += rx2_blk[None]
+        loglik_ref[0] += ll_blk
+        rsum_ref[0] += rsum_blk
+        rx_ref[0] += rx_blk
+        rx2_ref[0] += rx2_blk
     else:                                    # per-step partials (GPU)
-        loglik_ref[...] = ll_blk[None, None, None]
-        rsum_ref[...] = rsum_blk[None, None]
-        rx_ref[...] = rx_blk[None, None]
-        rx2_ref[...] = rx2_blk[None, None]
+        loglik_ref[0, 0] = ll_blk
+        rsum_ref[0, 0] = rsum_blk
+        rx_ref[0, 0] = rx_blk
+        rx2_ref[0, 0] = rx2_blk
 
 
 def gmm_estep_kernel(x, w, a, b, const, *, block_n: int = 1024,
@@ -85,7 +96,8 @@ def gmm_estep_kernel(x, w, a, b, const, *, block_n: int = 1024,
     x [Rx, Npad, Dpad], w [Rw, Npad], a/b [R, Kpad, Dpad], const [R, Kpad]
     (Rx, Rw ∈ {1, R}).  Returns (labels [R, Npad], loglik, r_sum, r_x,
     r_x2) with reduction outputs [R, ...] when ``accumulate`` else
-    per-step partials [R, S, ...].
+    per-step partials [R, S, ...].  Per-restart operands and outputs carry
+    a singleton axis inside the call, as in ``kmeans_assign_kernel``.
     """
     rx_, n, d = x.shape
     rw = w.shape[0]
@@ -96,51 +108,42 @@ def gmm_estep_kernel(x, w, a, b, const, *, block_n: int = 1024,
     grid = (r, s)
     xi = (lambda ri, i: (ri, i, 0)) if rx_ == r and r > 1 \
         else (lambda ri, i: (0, i, 0))
-    wi = (lambda ri, i: (ri, i)) if rw == r and r > 1 \
-        else (lambda ri, i: (0, i))
+    wi = (lambda ri, i: (ri, 0, i)) if rw == r and r > 1 \
+        else (lambda ri, i: (0, 0, i))
     if accumulate:
         red_specs = [
-            pl.BlockSpec((1, 1), lambda ri, i: (ri, 0)),         # loglik
-            pl.BlockSpec((1, k), lambda ri, i: (ri, 0)),         # r_sum
+            pl.BlockSpec((1, 1, 1), lambda ri, i: (ri, 0, 0)),   # loglik
+            pl.BlockSpec((1, k, 1), lambda ri, i: (ri, 0, 0)),   # r_sum
             pl.BlockSpec((1, k, d), lambda ri, i: (ri, 0, 0)),   # r_x
             pl.BlockSpec((1, k, d), lambda ri, i: (ri, 0, 0)),   # r_x2
         ]
-        red_shapes = [
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-            jax.ShapeDtypeStruct((r, k), jnp.float32),
-            jax.ShapeDtypeStruct((r, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((r, k, d), jnp.float32),
-        ]
+        red_shapes = [(r, 1, 1), (r, k, 1), (r, k, d), (r, k, d)]
     else:
         red_specs = [
-            pl.BlockSpec((1, 1, 1), lambda ri, i: (ri, i, 0)),
-            pl.BlockSpec((1, 1, k), lambda ri, i: (ri, i, 0)),
+            pl.BlockSpec((1, 1, 1, 1), lambda ri, i: (ri, i, 0, 0)),
+            pl.BlockSpec((1, 1, k, 1), lambda ri, i: (ri, i, 0, 0)),
             pl.BlockSpec((1, 1, k, d), lambda ri, i: (ri, i, 0, 0)),
             pl.BlockSpec((1, 1, k, d), lambda ri, i: (ri, i, 0, 0)),
         ]
-        red_shapes = [
-            jax.ShapeDtypeStruct((r, s, 1), jnp.float32),
-            jax.ShapeDtypeStruct((r, s, k), jnp.float32),
-            jax.ShapeDtypeStruct((r, s, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((r, s, k, d), jnp.float32),
-        ]
-    return pl.pallas_call(
+        red_shapes = [(r, s, 1, 1), (r, s, k, 1), (r, s, k, d), (r, s, k, d)]
+    labels, loglik, r_sum, r_x, r_x2 = pl.pallas_call(
         functools.partial(_kernel, accumulate=accumulate),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_n, d), xi),
-            pl.BlockSpec((1, block_n), wi),
+            pl.BlockSpec((1, 1, block_n), wi),
             pl.BlockSpec((1, k, d), lambda ri, i: (ri, 0, 0)),
             pl.BlockSpec((1, k, d), lambda ri, i: (ri, 0, 0)),
-            pl.BlockSpec((1, k), lambda ri, i: (ri, 0)),
+            pl.BlockSpec((1, k, 1), lambda ri, i: (ri, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_n), lambda ri, i: (ri, i)),
+            pl.BlockSpec((1, 1, block_n), lambda ri, i: (ri, 0, i)),
             *red_specs,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((r, n), jnp.int32),
-            *red_shapes,
+            jax.ShapeDtypeStruct((r, 1, n), jnp.int32),
+            *(jax.ShapeDtypeStruct(sh, jnp.float32) for sh in red_shapes),
         ],
         interpret=interpret,
-    )(x, w, a, b, const)
+    )(x, w[:, None, :], a, b, const[..., None])
+    return labels[:, 0], loglik[..., 0], r_sum[..., 0], r_x, r_x2

@@ -122,7 +122,7 @@ def gmm_estep(x, means, var, log_w, *, mask=None, block_n: int | None = None,
     active autotune cache (``kernels.autotune.tuning`` scope) >
     ``TilePolicy`` default — always ``block_for``-aligned.
     """
-    b = dispatch.resolve_backend(backend, interpret)
+    b, _ = OP.impl(backend, interpret)       # unregistered names fail here
     pol = layout.tile_policy(b)
     n = x.shape[-2]
     if block_n is None:

@@ -10,6 +10,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# f32 matmuls: the TPU's default precision multiplies in bf16
+_HI = jax.lax.Precision.HIGHEST
+
 _LOG2PI = 1.8378770664093453
 
 
@@ -22,8 +25,8 @@ def gmm_estep_masked_ref(x, w, means, var, log_w):
     x = x.astype(jnp.float32)
     w = w.astype(jnp.float32)
     inv_var = 1.0 / var
-    quad = ((x * x) @ inv_var.T
-            - 2.0 * (x @ (means * inv_var).T)
+    quad = (jnp.matmul(x * x, inv_var.T, precision=_HI)
+            - 2.0 * jnp.matmul(x, (means * inv_var).T, precision=_HI)
             + jnp.sum(means ** 2 * inv_var, axis=-1)[None, :])
     log_det = jnp.sum(jnp.log(var), axis=-1)
     d = x.shape[-1]
@@ -32,7 +35,8 @@ def gmm_estep_masked_ref(x, w, means, var, log_w):
     resp = jnp.exp(lp - lse[:, None]) * w[:, None]
     labels = jnp.argmax(lp, axis=-1).astype(jnp.int32)
     return (jnp.where(w > 0, labels, -1), jnp.sum(lse * w),
-            jnp.sum(resp, axis=0), resp.T @ x, resp.T @ (x * x))
+            jnp.sum(resp, axis=0), jnp.matmul(resp.T, x, precision=_HI),
+            jnp.matmul(resp.T, x * x, precision=_HI))
 
 
 def gmm_estep_ref(x, means, var, log_w):

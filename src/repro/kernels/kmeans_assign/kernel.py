@@ -38,6 +38,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _dot(a, b):
+    """f32 MXU matmul (Mosaic's default contraction precision for f32
+    operands is not guaranteed to be f32)."""
+    return jax.lax.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
 def _kernel(x_ref, w_ref, c_ref, labels_ref, sums_ref, counts_ref, j_ref,
             *, accumulate: bool):
     step = pl.program_id(1)
@@ -49,37 +56,41 @@ def _kernel(x_ref, w_ref, c_ref, labels_ref, sums_ref, counts_ref, j_ref,
             counts_ref[...] = jnp.zeros_like(counts_ref)
             j_ref[...] = jnp.zeros_like(j_ref)
 
+    # Every value stays 2-D: per-point quantities are [1, T] rows with the
+    # points on the lanes, per-cluster ones are [K, 1] columns (Mosaic has
+    # no lowering for reducing a 1-D lane vector to a scalar).
     x = x_ref[0].astype(jnp.float32)              # [T, D]
-    w = w_ref[0].astype(jnp.float32)              # [T]
+    w = w_ref[0].astype(jnp.float32)              # [1, T]
     c = c_ref[0].astype(jnp.float32)              # [K, D]
-    t, _ = x.shape
     k = c.shape[0]
+    xt = x.T                                      # [D, T]
 
-    x2 = jnp.sum(x * x, axis=-1, keepdims=True)                  # [T, 1]
-    c2 = jnp.sum(c * c, axis=-1)                                 # [K]
-    d2 = x2 - 2.0 * jax.lax.dot(x, c.T,                           # MXU matmul
-                                preferred_element_type=jnp.float32)
-    d2 = d2 + c2[None, :]
+    x2 = jnp.sum(xt * xt, axis=0, keepdims=True)                 # [1, T]
+    c2 = jnp.sum(c * c, axis=1, keepdims=True)                   # [K, 1]
+    d2 = x2 - 2.0 * _dot(c, xt)                                  # MXU matmul
+    d2 = d2 + c2                                                 # [K, T]
 
-    labels = jnp.argmin(d2, axis=-1).astype(jnp.int32)           # [T]
-    mind2 = jnp.maximum(jnp.min(d2, axis=-1), 0.0)               # [T]
+    # argmin over K as the first row attaining the minimum
+    mind2 = jnp.min(d2, axis=0, keepdims=True)                   # [1, T]
+    rows = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
+    labels = jnp.min(jnp.where(d2 == mind2, rows, k), axis=0,
+                     keepdims=True)                              # [1, T]
     valid = w > 0.0
 
-    labels_ref[...] = jnp.where(valid, labels, -1)[None]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (t, k), 1)
-    onehot = (labels[:, None] == cols).astype(jnp.float32) * w[:, None]
-    j_blk = jnp.sum(mind2 * w)
-    sums_blk = jax.lax.dot(onehot.T, x,                          # [K, D] MXU
-                           preferred_element_type=jnp.float32)
-    counts_blk = jnp.sum(onehot, axis=0)
+    labels_ref[0] = jnp.where(valid, labels, -1)
+    onehot = (rows == labels).astype(jnp.float32) * w            # [K, T]
+    j_blk = jnp.sum(jnp.maximum(mind2, 0.0) * w, axis=1,
+                    keepdims=True)                               # [1, 1]
+    sums_blk = _dot(onehot, x)                                   # [K, D] MXU
+    counts_blk = jnp.sum(onehot, axis=1, keepdims=True)          # [K, 1]
     if accumulate:
-        j_ref[...] += j_blk[None, None]
-        sums_ref[...] += sums_blk[None]
-        counts_ref[...] += counts_blk[None]
+        j_ref[0] += j_blk
+        sums_ref[0] += sums_blk
+        counts_ref[0] += counts_blk
     else:                                        # per-step partials (GPU)
-        j_ref[...] = j_blk[None, None, None]
-        sums_ref[...] = sums_blk[None, None]
-        counts_ref[...] = counts_blk[None, None]
+        j_ref[0, 0] = j_blk
+        sums_ref[0, 0] = sums_blk
+        counts_ref[0, 0] = counts_blk
 
 
 def kmeans_assign_kernel(x, w, centroids, *, block_n: int = 1024,
@@ -91,6 +102,11 @@ def kmeans_assign_kernel(x, w, centroids, *, block_n: int = 1024,
     (labels [R, Npad] i32, sums, counts, j) — reduction outputs are
     [R, ...] when ``accumulate`` else per-step partials [R, S, ...] for the
     wrapper to sum (parallel-grid backends).
+
+    Inside the call every per-restart operand and output carries a
+    singleton axis ([R, 1, Npad] labels and weights, [R, Kpad, 1] counts,
+    [R, 1, 1] J), so each block's last two dims equal the array's and the
+    restart grid is legal for any R under the TPU block-shape rule.
     """
     rx, n, d = x.shape
     rw = w.shape[0]
@@ -101,45 +117,38 @@ def kmeans_assign_kernel(x, w, centroids, *, block_n: int = 1024,
     grid = (r, s)
     xi = (lambda ri, i: (ri, i, 0)) if rx == r and r > 1 \
         else (lambda ri, i: (0, i, 0))
-    wi = (lambda ri, i: (ri, i)) if rw == r and r > 1 \
-        else (lambda ri, i: (0, i))
+    wi = (lambda ri, i: (ri, 0, i)) if rw == r and r > 1 \
+        else (lambda ri, i: (0, 0, i))
     if accumulate:
         red_specs = [
             pl.BlockSpec((1, k, d), lambda ri, i: (ri, 0, 0)),   # sums
-            pl.BlockSpec((1, k), lambda ri, i: (ri, 0)),         # counts
-            pl.BlockSpec((1, 1), lambda ri, i: (ri, 0)),         # J
+            pl.BlockSpec((1, k, 1), lambda ri, i: (ri, 0, 0)),   # counts
+            pl.BlockSpec((1, 1, 1), lambda ri, i: (ri, 0, 0)),   # J
         ]
-        red_shapes = [
-            jax.ShapeDtypeStruct((r, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((r, k), jnp.float32),
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-        ]
+        red_shapes = [(r, k, d), (r, k, 1), (r, 1, 1)]
     else:
         red_specs = [
             pl.BlockSpec((1, 1, k, d), lambda ri, i: (ri, i, 0, 0)),
-            pl.BlockSpec((1, 1, k), lambda ri, i: (ri, i, 0)),
-            pl.BlockSpec((1, 1, 1), lambda ri, i: (ri, i, 0)),
+            pl.BlockSpec((1, 1, k, 1), lambda ri, i: (ri, i, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1), lambda ri, i: (ri, i, 0, 0)),
         ]
-        red_shapes = [
-            jax.ShapeDtypeStruct((r, s, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((r, s, k), jnp.float32),
-            jax.ShapeDtypeStruct((r, s, 1), jnp.float32),
-        ]
-    return pl.pallas_call(
+        red_shapes = [(r, s, k, d), (r, s, k, 1), (r, s, 1, 1)]
+    labels, sums, counts, j = pl.pallas_call(
         functools.partial(_kernel, accumulate=accumulate),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_n, d), xi),              # points tile
-            pl.BlockSpec((1, block_n), wi),                 # row weights
+            pl.BlockSpec((1, 1, block_n), wi),              # row weights
             pl.BlockSpec((1, k, d), lambda ri, i: (ri, 0, 0)),  # centroids
         ],
         out_specs=[
-            pl.BlockSpec((1, block_n), lambda ri, i: (ri, i)),  # labels
+            pl.BlockSpec((1, 1, block_n), lambda ri, i: (ri, 0, i)),  # labels
             *red_specs,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((r, n), jnp.int32),
-            *red_shapes,
+            jax.ShapeDtypeStruct((r, 1, n), jnp.int32),
+            *(jax.ShapeDtypeStruct(sh, jnp.float32) for sh in red_shapes),
         ],
         interpret=interpret,
-    )(x, w, centroids)
+    )(x, w[:, None, :], centroids)
+    return labels[:, 0], sums, counts[..., 0], j[..., 0]

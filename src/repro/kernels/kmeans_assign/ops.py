@@ -129,7 +129,7 @@ def kmeans_assign(x, centroids, *, mask=None, block_n: int | None = None,
     ``TilePolicy`` default applies, bit-for-bit as before.  Either way
     the block passes through ``TilePolicy.block_for`` alignment.
     """
-    b = dispatch.resolve_backend(backend, interpret)
+    b, _ = OP.impl(backend, interpret)       # unregistered names fail here
     pol = layout.tile_policy(b)
     n = x.shape[-2]
     if block_n is None:

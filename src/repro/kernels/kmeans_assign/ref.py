@@ -7,6 +7,7 @@ historical ``kmeans_assign_ref`` signature wraps it with unit weights.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -21,7 +22,9 @@ def kmeans_assign_masked_ref(x, w, centroids):
     w = w.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     c2 = jnp.sum(c * c, axis=-1)
-    d2 = x2 - 2.0 * (x @ c.T) + c2[None, :]
+    # f32 matmul: the TPU's default precision multiplies in bf16
+    d2 = (x2 - 2.0 * jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
+          + c2[None, :])
     labels = jnp.argmin(d2, axis=-1).astype(jnp.int32)
     mind2 = jnp.maximum(jnp.min(d2, axis=-1), 0.0)
     j = jnp.sum(mind2 * w)

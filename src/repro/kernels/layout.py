@@ -88,11 +88,21 @@ TILE_POLICIES: dict[str, TilePolicy] = {
     # the smaller row block keeps one (block, D) tile within shared memory
     "gpu": TilePolicy(block_rows=256, row_align=16, k_align=16, d_align=32,
                       pow2=True),
+    # the jnp reference pads nothing and ignores block_n; the TPU policy
+    # only keeps the one block the ops and the autotuner pass it aligned
+    "xla": _TPU_POLICY,
 }
 
 
 def tile_policy(backend: str) -> TilePolicy:
-    return TILE_POLICIES.get(backend, _TPU_POLICY)
+    try:
+        return TILE_POLICIES[backend]
+    except KeyError:
+        raise ValueError(
+            f"no tile policy for kernel backend {backend!r} (known: "
+            f"{sorted(TILE_POLICIES)}); add one to layout.TILE_POLICIES "
+            "for a backend that pads"
+        ) from None
 
 
 # --------------------------------------------------------------------------

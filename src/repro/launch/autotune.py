@@ -68,6 +68,8 @@ def _parse_shapes(csv):
 
 def main(argv=None) -> int:
     args = _parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.enable()
     import os
 
     from repro.kernels import autotune

@@ -29,10 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat  # noqa: F401  (shard_map / make_mesh shims)
 from repro import core
 from repro.core import em_gmm
 from repro.data import load as load_data, spacenet_pixels
+from repro.launch import compile_cache
 
 
 def train_regression(groups, k: int, algorithm: str, *, max_iters: int,
@@ -91,15 +91,14 @@ def _data_mesh():
 
 
 def _resolve_shard(shard: bool, n_devices: int) -> bool:
-    """--shard on a 1-device host cannot shard anything: say so out loud
-    (with the fix) instead of silently running the replicated path while
-    the user believes the distributed drivers were exercised."""
+    """--shard on a 1-device host cannot shard anything: refuse, rather
+    than run the single-device path while the user believes the
+    distributed drivers ran."""
     if shard and n_devices < 2:
-        print("[cluster] --shard requested but only 1 device is visible; "
-              "falling back to the single-device path.  Hint: set "
-              "XLA_FLAGS=--xla_force_host_platform_device_count=N before "
-              "launch to exercise the distributed drivers on one host.")
-        return False
+        raise ValueError(
+            "--shard needs more than one device, but only 1 is visible.  "
+            "On a CPU host set XLA_FLAGS=--xla_force_host_platform_device_"
+            "count=N before launch to run the distributed drivers.")
     return shard
 
 
@@ -225,8 +224,10 @@ def run_production(x, k: int, algorithm: str, h_star: float, *,
     return out + (res.params,) if return_params else out
 
 
-def main():
-    ap = argparse.ArgumentParser()
+def parse_args(argv=None):
+    """The CLI's flags → argparse Namespace, with the minibatch defaults
+    resolved (the bare ``--mode minibatch`` recipe runs)."""
+    ap = argparse.ArgumentParser(prog="repro.launch.cluster")
     ap.add_argument("--dataset", default="skin",
                     choices=["road3d", "skin", "poker", "spacenet"])
     ap.add_argument("--k", type=int, default=2)
@@ -288,7 +289,7 @@ def main():
                          "loadable by serve_cluster --registry")
     ap.add_argument("--instance", default="m5.large")
     ap.add_argument("--out", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.kernel_backend != "auto" and not args.use_kernel:
         ap.error("--kernel-backend only applies with --use-kernel")
@@ -309,7 +310,11 @@ def main():
             defaulted.append(f"--batch-chunks {args.batch_chunks}")
         if defaulted:
             print("[cluster] minibatch defaults: " + " ".join(defaulted))
+    return args
 
+
+def load_groups(args):
+    """(training groups, production groups) drawn from ``args.dataset``."""
     n_prod = max(args.prod_groups, 1)
     if args.dataset == "spacenet":
         groups = spacenet_pixels(n_images=args.train_groups + n_prod,
@@ -318,8 +323,15 @@ def main():
         data = load_data(args.dataset, n=args.n)
         groups = core.random_groups(data, args.group_size,
                                     max_groups=args.train_groups + n_prod)
-    train_g, prod_g = groups[:args.train_groups], groups[args.train_groups:]
+    return groups[:args.train_groups], groups[args.train_groups:]
 
+
+def pipeline(args, train_g, prod_g) -> dict:
+    """The paper's pipeline on given groups: fit h(r) on ``train_g``, then
+    per production group an early-stopped run at h* = f(r*) and the
+    full-convergence reference.  Returns the report (the ``--out`` JSON
+    fields, plus per-group ``iters_*``/``accuracies``, the fitted
+    ``model`` and the first production group's ``params``)."""
     family = None if args.family == "auto" else args.family
     train_mode = args.train_mode or (
         "matched" if args.mode == "minibatch" else "full")
@@ -345,16 +357,12 @@ def main():
           f"R²={model.regression.metrics.r2:.4f}")
     print(f"h*({args.desired_accuracy}) = {h_star:.3e}   "
           f"(training took {t_train:.1f}s, amortised — Eq. 9)")
-    if args.save_model:
-        with open(args.save_model, "w") as f:
-            f.write(model.to_json() + "\n")
-        print(f"saved LongTailModel → {args.save_model}")
 
     # production: each group is one clustering task — the paper's unit of
     # work (§5.2 "image = group"; the regression transfers within-regime)
     t_actual = t_full = 0.0
     accs, iters_es, iters_fu = [], [], []
-    artifact_params = None
+    params = None
     for gi, g in enumerate(prod_g):
         # the fitted LongTailModel drives the threshold through EngineConfig
         labels, j, it1, t1, *rest = run_production(
@@ -365,9 +373,9 @@ def main():
             kernel_backend=args.kernel_backend,
             model=model, desired_accuracy=args.desired_accuracy,
             stats_compression=args.stats_compression, prefetch=args.prefetch,
-            return_params=(args.save_artifact is not None and gi == 0))
+            return_params=(gi == 0))
         if rest:
-            artifact_params = rest[0]
+            params = rest[0]
         # the full-convergence baseline always runs full sweeps — it is the
         # Time_full / 100%-accuracy reference the savings are measured from
         labels_f, j_f, it2, t2 = run_production(
@@ -381,18 +389,6 @@ def main():
         iters_es.append(int(it1))
         iters_fu.append(int(it2))
     acc = float(np.mean(accs))
-    if args.save_artifact:
-        # host-side copy of the first group's early-stopped fit, paired
-        # with the stop-model that certified it — the registry unit
-        # serve_cluster --registry loads
-        art = core.ClusterArtifact(
-            name=f"{args.dataset}-{args.algorithm}-k{args.k}",
-            algorithm=args.algorithm,
-            params=jax.tree.map(np.asarray, artifact_params),
-            model=model, desired_accuracy=args.desired_accuracy)
-        art.save(args.save_artifact)
-        print(f"saved ClusterArtifact ({art.k} clusters, d={art.d}) → "
-              f"{args.save_artifact}")
     rep = core.report(t_actual, t_full, time_train_s=t_train,
                       instance=args.instance)
     print(f"early-stop: {iters_es} iters {t_actual:.2f}s | "
@@ -400,20 +396,51 @@ def main():
           f"{acc:.4f} (per group: {[round(a, 3) for a in accs]})")
     print(f"cost-effectiveness (Eq.10) = {rep.cost_effectiveness:.3f}  "
           f"savings = ${rep.savings_usd:.6f} on {args.instance}")
+    return {
+        "dataset": args.dataset, "k": args.k,
+        "algorithm": args.algorithm, "mode": args.mode,
+        "desired_accuracy": args.desired_accuracy,
+        "achieved_accuracy": acc, "h_star": h_star,
+        "iters_earlystop": sum(iters_es), "iters_full": sum(iters_fu),
+        "time_actual_s": t_actual, "time_full_s": t_full,
+        "time_train_s": t_train,
+        "cost_effectiveness": rep.cost_effectiveness,
+        "regression": json.loads(model.to_json()),
+        "accuracies": accs, "iters_earlystop_per_group": iters_es,
+        "iters_full_per_group": iters_fu,
+        "model": model, "params": params,
+    }
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    compile_cache.enable()
+    train_g, prod_g = load_groups(args)
+    res = pipeline(args, train_g, prod_g)
+    model = res["model"]
+    if args.save_model:
+        with open(args.save_model, "w") as f:
+            f.write(model.to_json() + "\n")
+        print(f"saved LongTailModel → {args.save_model}")
+    if args.save_artifact:
+        # host-side copy of the first group's early-stopped fit, paired
+        # with the stop-model that certified it — the registry unit
+        # serve_cluster --registry loads
+        art = core.ClusterArtifact(
+            name=f"{args.dataset}-{args.algorithm}-k{args.k}",
+            algorithm=args.algorithm,
+            params=jax.tree.map(np.asarray, res["params"]),
+            model=model, desired_accuracy=args.desired_accuracy)
+        art.save(args.save_artifact)
+        print(f"saved ClusterArtifact ({art.k} clusters, d={art.d}) → "
+              f"{args.save_artifact}")
     if args.out:
+        keep = ("dataset", "k", "algorithm", "mode", "desired_accuracy",
+                "achieved_accuracy", "h_star", "iters_earlystop",
+                "iters_full", "time_actual_s", "time_full_s",
+                "time_train_s", "cost_effectiveness", "regression")
         with open(args.out, "w") as f:
-            json.dump({
-                "dataset": args.dataset, "k": args.k,
-                "algorithm": args.algorithm, "mode": args.mode,
-                "desired_accuracy": args.desired_accuracy,
-                "achieved_accuracy": acc, "h_star": h_star,
-                "iters_earlystop": sum(iters_es),
-                "iters_full": sum(iters_fu),
-                "time_actual_s": t_actual, "time_full_s": t_full,
-                "time_train_s": t_train,
-                "cost_effectiveness": rep.cost_effectiveness,
-                "regression": json.loads(model.to_json()),
-            }, f, indent=1)
+            json.dump({k: res[k] for k in keep}, f, indent=1)
 
 
 if __name__ == "__main__":

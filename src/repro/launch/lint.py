@@ -52,7 +52,6 @@ def main(argv=None) -> int:
         os.environ["XLA_FLAGS"] = \
             (os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
 
-    import repro.compat  # noqa: F401
     from repro.analysis import ast_rules, engine_contracts
     from repro.analysis.report import apply_suppressions, normalize_rule_ids
 

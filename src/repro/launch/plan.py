@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat  # noqa: F401  (shard_map / make_mesh shims)
 from repro import core
 from repro.core.cost_model import PriceTable, candidate_cost_usd
 from repro.core.engine import ClusteringEngine, EngineConfig
@@ -46,6 +45,7 @@ from repro.core.longtail_train import (TrainingPlan, fit_for_config,
 from repro.core.planner import (IterationModel, PlanError, PlanReport,
                                 PlanSpec, ThroughputModel, plan)
 from repro.data import load as load_data
+from repro.launch import compile_cache
 from repro.training.straggler import StragglerMonitor
 
 EXIT_OK = 0
@@ -271,6 +271,7 @@ def main(argv=None):
                     help="write the PlanReport (+ validation record) "
                          "JSON to PATH")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     prices = PriceTable.default()
     if args.prices:

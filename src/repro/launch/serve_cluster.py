@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.core import (ClusterArtifact, ClusteringEngine, EngineConfig,
                         TrainingPlan, fit_for_config, load_registry_dir)
+from repro.launch import compile_cache
 from repro.serving import AssignRequest, ClusterServer, FitRequest, ModelRegistry
 
 
@@ -102,6 +103,7 @@ def main():
     ap.add_argument("--out", default=None, metavar="PATH",
                     help="write the metrics summary as JSON")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.synthetic:
         artifacts = demo_artifacts(args.seed)

@@ -17,9 +17,10 @@ shape set closed, refill from a queue — to clustering workloads:
     small incremental minibatch-fit jobs) into fixed padded batch-bucket
     shapes (``kernels.layout.bucket_for``), so XLA compiles one program
     per (model, bucket).  The hot path runs through the backend-dispatched
-    assignment ops (``kernels.dispatch``: the artifact's pinned
-    ``kernel_backend`` when it was fitted with ``use_kernel``, the ``xla``
-    reference otherwise), with the ops' mask operand absorbing the bucket
+    assignment ops (``kernels.dispatch``: the platform's kernel backend —
+    compiled ``tpu`` on a TPU, ``interpret`` on a CPU — when the artifact
+    was fitted with ``use_kernel``, the ``xla`` reference otherwise), with
+    the ops' mask operand absorbing the bucket
     padding — padded rows are labelled −1 and dropped before the response
     is split back per request.
 

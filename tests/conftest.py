@@ -1,38 +1,23 @@
-"""Test substrate: in-process multi-device session + optional hypothesis.
+"""Test substrate: an in-process multi-device session.
 
-**Multi-device.** The whole test session runs with 8 XLA host-platform
-devices: the flag is appended to ``XLA_FLAGS`` below, *before* anything can
-import jax (pytest loads conftest first; the backend reads the flag at its
-lazy first initialisation).  The session-scoped ``mesh8`` fixture hands
-tests a real 8-device ``("d",)`` mesh, so multi-device paths (shard_map
-collectives, GSPMD lowering, sharded restore) run in-process instead of
-behind ``subprocess.run`` — same coverage, one process, debuggable.  An
+The whole test session runs with 8 XLA host-platform devices: the flag is
+appended to ``XLA_FLAGS`` below, *before* anything can import jax (pytest
+loads conftest first; the backend reads the flag at its lazy first
+initialisation).  The session-scoped ``mesh8`` fixture hands tests a real
+8-device ``("d",)`` mesh, so multi-device paths (shard_map collectives,
+GSPMD lowering, sharded restore) run in-process instead of behind
+``subprocess.run`` — same coverage, one process, debuggable.  An
 externally-set device-count flag wins (that is how CI pins the single- and
 multi-device legs); tests needing the mesh skip when fewer than 8 devices
 exist.  Single-device numerics are unchanged: computations still place onto
 device 0 unless a test shards them explicitly.
 
-**Hypothesis is optional.** The property-based suites (test_kernels,
-test_rand_index, test_regression, test_earlystop_and_cost, test_invariants)
-are written against the real hypothesis API.  On a bare JAX install this
-conftest registers a minimal, deterministic stand-in *before collection*:
-``@given`` becomes a seeded random sweep of ``max_examples`` draws (no
-shrinking, fixed seed), which keeps every property executed — just with
-fewer, reproducible examples.  Install ``requirements-dev.txt`` to run the
-full hypothesis engine instead; this module then does nothing.
-
-In the same spirit, importing ``repro.compat`` first installs jax
-forward-compat shims (jax.shard_map / AxisType / make_mesh(axis_types=))
-for older jaxlib builds.
+Hypothesis runs derandomized and without an example database, so a
+property test sees the same examples on every run.
 """
 from __future__ import annotations
 
-import functools
-import inspect
 import os
-import random
-import sys
-import types
 
 _DEVCOUNT_FLAG = "--xla_force_host_platform_device_count"
 # stash what the user actually set, so tests that spawn CLI subprocesses
@@ -40,10 +25,18 @@ _DEVCOUNT_FLAG = "--xla_force_host_platform_device_count"
 ORIG_XLA_FLAGS = os.environ.get("XLA_FLAGS", "")
 if _DEVCOUNT_FLAG not in ORIG_XLA_FLAGS:
     os.environ["XLA_FLAGS"] = (ORIG_XLA_FLAGS + f" {_DEVCOUNT_FLAG}=8").strip()
+# the persistent compilation cache stays off in this session and in the CLI
+# subprocesses it starts (they inherit the environment)
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
-import repro.compat  # noqa: F401,E402  (jax API shims; must precede test imports)
 import jax  # noqa: E402
 import pytest  # noqa: E402
+from hypothesis import settings  # noqa: E402
+
+# property tests draw the same examples on every run and write no example
+# database into the checkout
+settings.register_profile("repo", derandomize=True, database=None)
+settings.load_profile("repo")
 
 
 @pytest.fixture(scope="session")
@@ -55,137 +48,3 @@ def mesh8():
                     f"have {jax.device_count()})")
     return jax.make_mesh((8,), ("d",),
                          axis_types=(jax.sharding.AxisType.Auto,))
-
-try:  # real hypothesis wins whenever it is available
-    import hypothesis  # noqa: F401
-    _HAVE_HYPOTHESIS = True
-except ImportError:
-    _HAVE_HYPOTHESIS = False
-
-
-if not _HAVE_HYPOTHESIS:
-
-    class _Unsatisfied(Exception):
-        """Raised by assume(False): discard the current draw."""
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def example(self, rng: random.Random):
-            return self._draw(rng)
-
-    def _integers(min_value, max_value):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def _floats(min_value, max_value, **_kw):
-        return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-    def _booleans():
-        return _Strategy(lambda rng: rng.random() < 0.5)
-
-    def _none():
-        return _Strategy(lambda rng: None)
-
-    def _sampled_from(seq):
-        items = list(seq)
-        return _Strategy(lambda rng: rng.choice(items))
-
-    def _one_of(*strategies):
-        return _Strategy(lambda rng: rng.choice(strategies).example(rng))
-
-    def _tuples(*strategies):
-        return _Strategy(lambda rng: tuple(s.example(rng) for s in strategies))
-
-    def _lists(elements, min_size=0, max_size=None):
-        hi = max_size if max_size is not None else min_size + 10
-        return _Strategy(lambda rng: [
-            elements.example(rng) for _ in range(rng.randint(min_size, hi))])
-
-    def _permutations(seq):
-        items = list(seq)
-
-        def draw(rng):
-            out = list(items)
-            rng.shuffle(out)
-            return out
-        return _Strategy(draw)
-
-    def _just(value):
-        return _Strategy(lambda rng: value)
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.floats = _floats
-    _st.booleans = _booleans
-    _st.none = _none
-    _st.sampled_from = _sampled_from
-    _st.one_of = _one_of
-    _st.tuples = _tuples
-    _st.lists = _lists
-    _st.permutations = _permutations
-    _st.just = _just
-
-    _DEFAULT_MAX_EXAMPLES = 20
-    _EXAMPLE_CAP = 25          # keep bare-install sweeps fast
-
-    def _settings(max_examples=_DEFAULT_MAX_EXAMPLES, deadline=None, **_kw):
-        def deco(fn):
-            fn._mh_settings = {"max_examples": max_examples}
-            return fn
-        return deco
-
-    def _assume(condition):
-        if not condition:
-            raise _Unsatisfied()
-        return True
-
-    def _given(*garg_strategies, **gkw_strategies):
-        def deco(fn):
-            sig = inspect.signature(fn)
-            names = list(sig.parameters)
-            # hypothesis maps positional strategies to the RIGHTMOST params
-            pos_names = names[len(names) - len(garg_strategies):] \
-                if garg_strategies else []
-            filled = set(pos_names) | set(gkw_strategies)
-
-            @functools.wraps(fn)
-            def wrapper(**outer_kw):
-                cfg = getattr(wrapper, "_mh_settings", None) or \
-                    getattr(fn, "_mh_settings", {})
-                n = min(cfg.get("max_examples", _DEFAULT_MAX_EXAMPLES),
-                        _EXAMPLE_CAP)
-                rng = random.Random(0xC0FFEE)
-                for _ in range(n):
-                    kw = dict(outer_kw)
-                    for name, strat in zip(pos_names, garg_strategies):
-                        kw[name] = strat.example(rng)
-                    for name, strat in gkw_strategies.items():
-                        kw[name] = strat.example(rng)
-                    try:
-                        fn(**kw)
-                    except _Unsatisfied:
-                        continue
-                    except Exception:
-                        drawn = {k: v for k, v in kw.items() if k in filled}
-                        print(f"\n[mini-hypothesis] falsifying example: "
-                              f"{drawn}", file=sys.stderr)
-                        raise
-
-            # hide the strategy-filled params from pytest's fixture resolver
-            wrapper.__signature__ = sig.replace(parameters=[
-                p for name, p in sig.parameters.items() if name not in filled])
-            return wrapper
-        return deco
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.assume = _assume
-    _hyp.strategies = _st
-    _hyp.HealthCheck = types.SimpleNamespace(
-        too_slow=None, filter_too_much=None, data_too_large=None)
-    _hyp.__version__ = "0.0-mini"
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st

@@ -118,10 +118,13 @@ def test_cache_from_other_device_kind_never_matches():
                                      n=4096, k=8, d=16) is None
 
 
-def test_resolution_order_at_the_op_call_site():
+def test_resolution_order_at_the_op_call_site(monkeypatch):
     """explicit block_n > active cache > TilePolicy default, observed
     through a fake registered backend that records the resolved block."""
     seen = []
+    # a backend brings its own tile policy; the spy borrows the TPU one
+    monkeypatch.setitem(layout.TILE_POLICIES, "spybk",
+                        layout.TILE_POLICIES["tpu"])
 
     @dispatch.register_backend("kmeans_assign", "spybk")
     def _spy(x, w, c, *, block_n):

@@ -113,6 +113,26 @@ def test_provenance_mismatch_is_rejected_loudly():
     assert len(registry.keys()) == 1
 
 
+def test_kernel_artifact_serves_on_the_platform_backend():
+    """An artifact fitted with use_kernel on one platform is served by the
+    compiled kernel of another: its stamped backend (here "tpu") is not a
+    matched field, and the registry resolves the backend where it runs."""
+    from repro.kernels import dispatch
+    cfg = EngineConfig(max_iters=40, use_kernel=True, kernel_backend="tpu")
+    art = _kmeans_artifact("kern", cfg)
+    assert art.model.engine_config["use_kernel"] is True
+    registry = ModelRegistry()
+    key = registry.register(art)
+    entry = registry[key]
+    assert entry.backend == dispatch.default_backend()
+    srv = ClusterServer(registry, buckets=BUCKETS)
+    x = _batch(50, 9)
+    srv.submit(AssignRequest(x=x, model_key=key, rid=0))
+    _, ref_labels, _ = ClusteringEngine(
+        "kmeans", EngineConfig(max_iters=40)).step(x, entry.params)
+    np.testing.assert_array_equal(srv.drain()[0], np.asarray(ref_labels))
+
+
 def test_from_longtail_strict_raises_not_warns():
     model = _model_for(MB_CFG, "kmeans")
     with pytest.raises(ProvenanceMismatchError):

@@ -260,6 +260,29 @@ def test_sharded_int8_em_close(blobs, c0, mesh8):
                                rtol=1e-3)
 
 
+def test_sharded_int8_em_full_mode_close(blobs, c0, mesh8):
+    """Full-batch EM under the int8 ring: one int8 scale per component row
+    (a leaf-wide scale rounded the small components' moments to zero and
+    collapsed them to zero weight and variance) and second moments
+    centred on the current variances (which lets the rounding shrink as
+    the fit converges).  The compressed fit's h decays a few iterations
+    behind the fp32 fit's below ~1e-4 (the error-feedback residual is
+    still draining), so the stop is compared at h* = 1e-3."""
+    p0 = em_gmm.init_from_kmeans(blobs, c0)
+    cfg = dict(max_iters=100, chunks=8)
+    ref = ClusteringEngine("em", EngineConfig(**cfg)).fit_sharded(
+        blobs, p0, _data_mesh(mesh8), h_star=1e-3)
+    res = ClusteringEngine("em", EngineConfig(
+        stats_compression="int8_ef", **cfg)).fit_sharded(
+        blobs, p0, _data_mesh(mesh8), h_star=1e-3)
+    assert abs(int(res.n_iters) - int(ref.n_iters)) <= 1, \
+        (int(res.n_iters), int(ref.n_iters))
+    np.testing.assert_allclose(float(res.objective), float(ref.objective),
+                               rtol=1e-3)
+    # no component collapsed (a leaf-wide scale left two at weight 0)
+    assert float(np.exp(np.asarray(res.params.log_w)).min()) > 0.01
+
+
 def test_sharded_int8_restarts_best_agree(blobs, mesh8):
     """Per-restart EF state threads through the vmapped while_loop carry:
     the compressed fleet picks the same winner as the fp32 fleet."""

@@ -57,9 +57,8 @@ def test_change_rate_scale_invariant(alpha, j_prev, delta):
     the fitted h* transfers across objective scales (dataset sizes).
     Checked in f64 — in f32 the subtraction's cancellation noise would
     drown the property itself."""
-    from jax.experimental import enable_x64
     j_curr = j_prev + delta
-    with enable_x64():
+    with jax.enable_x64():
         h1 = float(change_rate(jnp.float64(j_curr), jnp.float64(j_prev)))
         h2 = float(change_rate(jnp.float64(alpha * j_curr),
                                jnp.float64(alpha * j_prev)))

@@ -149,13 +149,13 @@ def test_distributed_restarts_match_unsharded(mesh8):
 
 
 def test_shard_fallback_helper_is_loud(capsys):
-    """--shard on a 1-device host must announce the fallback, not silently
-    run replicated while the user believes the distributed path ran."""
+    """--shard on a 1-device host must fail, not silently run replicated
+    while the user believes the distributed path ran."""
     from repro.launch.cluster import _resolve_shard
-    assert _resolve_shard(True, 1) is False
-    out = capsys.readouterr().out
-    assert "--shard" in out and "only 1 device" in out
-    assert "xla_force_host_platform_device_count" in out   # the fix hint
+    with pytest.raises(ValueError, match="only 1 is visible") as ei:
+        _resolve_shard(True, 1)
+    assert "--shard" in str(ei.value)
+    assert "xla_force_host_platform_device_count" in str(ei.value)  # the fix
     assert _resolve_shard(True, 8) is True
     assert _resolve_shard(False, 1) is False
     assert capsys.readouterr().out == ""                   # quiet otherwise
@@ -163,14 +163,13 @@ def test_shard_fallback_helper_is_loud(capsys):
 
 @pytest.mark.skipif(jax.device_count() != 1,
                     reason="exercises the forced-1-device CI leg")
-def test_shard_single_device_end_to_end_warns(capsys):
-    """On the 1-device CI leg the whole production path must still work
-    under --shard, with the explicit fallback message."""
+def test_shard_single_device_end_to_end_warns():
+    """On the 1-device CI leg the production path refuses --shard before
+    any device work."""
     data = load("skin", n=2000, seed=0)
-    labels, _, _, _ = run_production(data, 2, "kmeans", 1e-3, max_iters=30,
-                                     seed=1, shard=True)
-    assert labels.shape[0] == 2000
-    assert "only 1 device" in capsys.readouterr().out
+    with pytest.raises(ValueError, match="only 1 is visible"):
+        run_production(data, 2, "kmeans", 1e-3, max_iters=30, seed=1,
+                       shard=True)
 
 
 def _cli_env():
