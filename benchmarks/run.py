@@ -541,7 +541,7 @@ def sharded_overlap():
         # the device counts are host-platform emulation: pin the CPU
         subprocess.run(cmd, check=True, cwd=root,
                        env=overlap_env({**os.environ, "JAX_PLATFORMS": "cpu"},
-                                       enable=enable, platform="cpu"))
+                                       enable=enable))
         with open(out) as f:
             legs[leg] = json.load(f)
         os.unlink(out)

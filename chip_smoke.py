@@ -17,13 +17,22 @@ the paper's data sizes.
     groups, then the early-stopped production run and the full-convergence
     reference on the whole data set.  k-means and EM on poker, on the
     default XLA path and on the kernel path; then skin with minibatch
-    sweeps, 4 restarts and the kernel.
+    sweeps, 4 restarts and the kernel.  Each line says what the early
+    stop did: "met r*" or "missed r*" (Rand index of the early stop
+    against the full run), or "not exercised" where the fitted h* sits on
+    ``threshold_for``'s floor and the run waits for the fit to stop
+    moving.  A summary line lists the runs under each; at least one run's
+    early stop must meet r*.
   · serve    — the k-means kernel-path fit saved as a ``ClusterArtifact``,
     registered and served by ``ClusterServer``; the served labels must
     equal the engine's assignment at the same parameters.
   · sharded  — (``--chips 4`` only) ``run_production(shard=True)`` on a
     4-device data mesh, k-means and EM, full and minibatch, 4 restarts,
-    fp32 and int8 stats reduction, each against the same fit on device 0.
+    fp32 and int8 stats reduction, each against the same fit on device 0:
+    stop iteration within ±1 and best objective within the restart-fleet
+    tolerances, at a fixed h* per algorithm.  The same fits also run at
+    the h* the pipeline fits for that regime (r* = 0.99); those lines
+    report the comparison (``gated: false``) and do not decide the run.
 
 Every check prints one JSON line naming the device kind, iterations,
 accuracy, and compile seconds apart from run seconds (from JAX's compile
@@ -233,8 +242,10 @@ def kernels_phase(x_np, k, restarts, *, backend, kind, clock, seed=0):
 def pipeline_phase(data, argv, *, kind, clock, group_size=GROUP_SIZE,
                    train_groups=TRAIN_GROUPS):
     """``repro.launch.cluster``'s pipeline with ``data`` as the one
-    production group and ``train_groups`` groups sampled from it."""
+    production group and ``train_groups`` groups sampled from it:
+    (report, run name, what the early stop did)."""
     from repro import core
+    from repro.core.regression import H_STAR_FLOOR
     from repro.launch import cluster
 
     args = cluster.parse_args(argv)
@@ -249,13 +260,20 @@ def pipeline_phase(data, argv, *, kind, clock, group_size=GROUP_SIZE,
     check(1 <= it_es <= args.max_iters, f"early stop at {it_es}")
     check(1 <= it_fu, f"full run stopped at {it_fu}")
     check(0.0 <= rand <= 1.0, f"Rand index {rand}")
+    meets = rand >= args.desired_accuracy
+    if h_star <= H_STAR_FLOOR:
+        early_stop = "not exercised (h* on the floor)"
+    else:
+        early_stop = "met r*" if meets else "missed r*"
     emit(phase="pipeline", dataset=args.dataset, n=int(data.shape[0]),
          algorithm=args.algorithm, mode=args.mode, restarts=args.restarts,
          path="kernel" if args.use_kernel else "xla", device_kind=kind,
          h_star=h_star, iters_earlystop=it_es, iters_full=it_fu,
          rand_index_vs_full=rand, desired_accuracy=args.desired_accuracy,
-         **t)
-    return res
+         meets_desired_accuracy=meets, early_stop=early_stop, **t)
+    name = f"{args.dataset}/{args.algorithm}/{args.mode}/" + (
+        "kernel" if args.use_kernel else "xla")
+    return res, name, early_stop
 
 
 # --------------------------------------------------------------------------
@@ -316,7 +334,8 @@ def serve_phase(res, data, k, *, backend, kind, clock, seed=0):
 # variance floor after a handful of iterations, and from there on even a
 # reordered fp32 psum moves the log-likelihood by ~1e-3.
 SHARDED_H_STAR = {"kmeans": 1e-4, "em": 1e-2}
-# best-objective tolerances of the restart-fleet tests in
+# the phase runs restart fleets (restarts=4) and compares their best
+# objectives, so it holds them to the restart-fleet tests' tolerances in
 # tests/test_engine_sharded.py: an fp32 psum reorders the sum (1e-4), the
 # int8_ef ring quantises the stats (1e-2)
 SHARDED_RTOL = {"none": 1e-4, "int8_ef": 1e-2}
@@ -330,7 +349,7 @@ def sharded_phase(data, k, n_dev, *, kind, clock, restarts=RESTARTS,
     import jax.numpy as jnp
     from repro import core
     from repro.core import ClusteringEngine, EngineConfig
-    from repro.launch.cluster import run_production
+    from repro.launch import cluster
 
     devices = jax.devices()
     check(len(devices) >= n_dev, f"{len(devices)} devices, need {n_dev}")
@@ -352,36 +371,58 @@ def sharded_phase(data, k, n_dev, *, kind, clock, restarts=RESTARTS,
     emit(phase="sharded", check="placement", device_kind=kind,
          devices=n_dev, shard_shape=list(shards[0].data.shape))
 
+    train = core.random_groups(data, GROUP_SIZE, max_groups=TRAIN_GROUPS,
+                               seed=1)
     failed = []
     for alg in ("kmeans", "em"):
         for mode in ("full", "minibatch"):
             kw = dict(max_iters=max_iters, seed=0, restarts=restarts,
                       chunks=chunks, mode=mode,
                       batch_chunks=batch_chunks if mode == "minibatch" else 0)
-            h = SHARDED_H_STAR[alg]
-            with clock.timed() as t1:
-                _, j1, it1, _ = run_production(data, k, alg, h, **kw)
-            for comp in ("none", "int8_ef"):
-                with clock.timed() as t:
-                    _, js, its, _, params = run_production(
-                        data, k, alg, h, shard=True, stats_compression=comp,
-                        return_params=True, **kw)
-                rtol = SHARDED_RTOL[comp]
-                ok = abs(its - it1) <= 1 and np.isclose(js, j1, rtol=rtol)
-                extra = {}
-                if alg == "em":
-                    w = float(np.exp(np.asarray(params.log_w)).min())
-                    ok = ok and w > MIN_EM_WEIGHT
-                    extra["min_weight"] = w
-                emit(phase="sharded", algorithm=alg, mode=mode,
-                     restarts=restarts, stats_compression=comp,
-                     devices=n_dev, device_kind=kind, h_star=h,
-                     iters=its, iters_device0=it1, objective=js,
-                     objective_device0=j1, rtol=rtol, **extra,
-                     parity="pass" if ok else "FAIL",
-                     device0_compile_s=t1["compile_s"],
-                     device0_run_s=t1["run_s"], **t)
-                failed += [] if ok else [f"{alg}/{mode}/{comp}"]
+            # the stop model the pipeline fits for this regime
+            args = cluster.parse_args([
+                "--dataset", "poker", "--k", str(k), "--algorithm", alg,
+                "--mode", mode, "--chunks", str(chunks),
+                "--batch-chunks", str(kw["batch_chunks"]),
+                "--restarts", str(restarts), "--max-iters", str(max_iters),
+                "--family", "auto",
+                "--desired-accuracy", str(DESIRED_ACCURACY)])
+            model, _, _ = cluster.fit_stop_model(args, train)
+            fitted = dict(model=model, desired_accuracy=DESIRED_ACCURACY)
+            for stop, h, extra_kw, gated in (
+                    ("fixed", SHARDED_H_STAR[alg], {}, True),
+                    ("fitted", model.threshold_for(DESIRED_ACCURACY),
+                     fitted, False)):
+                with clock.timed() as t1:
+                    _, j1, it1, _ = cluster.run_production(
+                        data, k, alg, h, **extra_kw, **kw)
+                for comp in ("none", "int8_ef"):
+                    with clock.timed() as t:
+                        _, js, its, _, params = cluster.run_production(
+                            data, k, alg, h, shard=True,
+                            stats_compression=comp, return_params=True,
+                            **extra_kw, **kw)
+                    rtol = SHARDED_RTOL[comp]
+                    ok = abs(its - it1) <= 1 and np.isclose(js, j1,
+                                                            rtol=rtol)
+                    extra = {}
+                    if alg == "em":
+                        w = float(np.exp(np.asarray(params.log_w)).min())
+                        ok = ok and w > MIN_EM_WEIGHT
+                        extra["min_weight"] = w
+                    emit(phase="sharded", algorithm=alg, mode=mode,
+                         restarts=restarts, stats_compression=comp,
+                         devices=n_dev, device_kind=kind, stop=stop,
+                         h_star=h, gated=gated, iters=its,
+                         iters_device0=it1, objective=js,
+                         objective_device0=j1,
+                         objective_rel_diff=abs(js - j1) / abs(j1),
+                         rtol=rtol, **extra,
+                         parity="pass" if ok else "FAIL",
+                         device0_compile_s=t1["compile_s"],
+                         device0_run_s=t1["run_s"], **t)
+                    if gated and not ok:
+                        failed.append(f"{alg}/{mode}/{comp}")
     check(not failed, f"sharded fits off their device-0 twins: {failed}")
 
 
@@ -427,22 +468,29 @@ def main(argv=None) -> None:
         common = ["--dataset", "poker", "--k", str(POKER_K),
                   "--max-iters", str(MAX_ITERS), "--family", "auto",
                   "--desired-accuracy", str(DESIRED_ACCURACY)]
-        fits = {}
+        runs = {}
         for alg in ("kmeans", "em"):
             for kernel in (False, True):
-                fits[alg, kernel] = pipeline_phase(
+                runs[alg, kernel] = pipeline_phase(
                     poker, common + ["--algorithm", alg]
                     + (["--use-kernel"] if kernel else []),
                     kind=kind, clock=clock)
         skin = load("skin", n=PAPER_SIZES["skin"])
-        pipeline_phase(skin, [
+        runs["skin"] = pipeline_phase(skin, [
             "--dataset", "skin", "--k", str(SKIN_K), "--mode", "minibatch",
             "--chunks", "8", "--batch-chunks", "2",
             "--restarts", str(RESTARTS), "--use-kernel",
             "--max-iters", str(MAX_ITERS), "--family", "auto",
             "--desired-accuracy", str(DESIRED_ACCURACY)],
             kind=kind, clock=clock)
-        serve_phase(fits["kmeans", True], poker, POKER_K, backend="tpu",
+        summary = {}
+        for _, name, early_stop in runs.values():
+            summary.setdefault(early_stop, []).append(name)
+        emit(phase="pipeline", summary=True, device_kind=kind,
+             early_stop=summary)
+        check(summary.get("met r*"),
+              "no pipeline run's early stop certified r*")
+        serve_phase(runs["kmeans", True][0], poker, POKER_K, backend="tpu",
                     kind=kind, clock=clock)
 
     print(json.dumps({"ok": True, "device": {

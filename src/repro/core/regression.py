@@ -28,6 +28,10 @@ _HI = jax.lax.Precision.HIGHEST
 FAMILIES = ("linear", "quadratic", "cubic", "exponential", "lasso_quadratic",
             "log_quadratic")
 
+# the smallest h* threshold_for returns: a fit that reaches h <= 0 below r*
+# lands here, and a stop at it waits for the fit to stop moving at all
+H_STAR_FLOOR = 1e-12
+
 
 @dataclasses.dataclass(frozen=True)
 class FitMetrics:
@@ -61,7 +65,8 @@ class RegressionModel:
             return jnp.exp(b0 + b1 * r + b2 * r * r)
         raise ValueError(f"unknown family {self.family}")
 
-    def threshold_for(self, desired_accuracy: float, floor: float = 1e-12) -> float:
+    def threshold_for(self, desired_accuracy: float,
+                      floor: float = H_STAR_FLOOR) -> float:
         """h* = f(r*): the change-rate threshold for a desired accuracy (§4).
 
         The fitted curve should be decreasing in r; a noisy quadratic can

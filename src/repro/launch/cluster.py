@@ -326,12 +326,9 @@ def load_groups(args):
     return groups[:args.train_groups], groups[args.train_groups:]
 
 
-def pipeline(args, train_g, prod_g) -> dict:
-    """The paper's pipeline on given groups: fit h(r) on ``train_g``, then
-    per production group an early-stopped run at h* = f(r*) and the
-    full-convergence reference.  Returns the report (the ``--out`` JSON
-    fields, plus per-group ``iters_*``/``accuracies``, the fitted
-    ``model`` and the first production group's ``params``)."""
+def fit_stop_model(args, train_g):
+    """h(r) fitted on ``train_g`` for the production regime ``args``
+    describes: (LongTailModel, training seconds, train mode)."""
     family = None if args.family == "auto" else args.family
     train_mode = args.train_mode or (
         "matched" if args.mode == "minibatch" else "full")
@@ -351,6 +348,16 @@ def pipeline(args, train_g, prod_g) -> dict:
                                       max_iters=args.max_iters, family=family,
                                       train_mode=train_mode,
                                       production_config=production_cfg)
+    return model, t_train, train_mode
+
+
+def pipeline(args, train_g, prod_g) -> dict:
+    """The paper's pipeline on given groups: fit h(r) on ``train_g``, then
+    per production group an early-stopped run at h* = f(r*) and the
+    full-convergence reference.  Returns the report (the ``--out`` JSON
+    fields, plus per-group ``iters_*``/``accuracies``, the fitted
+    ``model`` and the first production group's ``params``)."""
+    model, t_train, train_mode = fit_stop_model(args, train_g)
     h_star = model.threshold_for(args.desired_accuracy)
     print(f"regression ({model.regression.family}, {train_mode} harvest): "
           f"coeffs={[round(c, 6) for c in model.regression.coeffs]} "

@@ -24,48 +24,42 @@ import os
 import jax
 
 
-# The scheduler/stream flags this jaxlib's XLA still parses, per platform.
-# The full SNIPPETS.md set also named --xla_gpu_enable_async_collectives
-# and the Triton fusion toggles; async collectives are default-on (the flag
-# was removed upstream) and unknown flags make XLA abort at startup, so
-# they are deliberately absent here.  libtpu parses the gpu and cpu flags
-# too (one XLA flag parser; checked on a v5e), but they configure other
-# backends, so the TPU set is empty.
-LATENCY_HIDING_FLAGS = {
-    "gpu": ("--xla_gpu_enable_latency_hiding_scheduler=true",
-            "--xla_gpu_enable_highest_priority_async_stream=true"),
-    # the concurrency-optimized thunk scheduler overlaps independent
-    # thunks (our prefetched chunk copies) on host platforms
-    "cpu": ("--xla_cpu_enable_concurrency_optimized_scheduler=true",),
-    "tpu": (),
-}
+# The scheduler/stream flags this jaxlib's XLA still parses.  The full
+# SNIPPETS.md set also named --xla_gpu_enable_async_collectives and the
+# Triton fusion toggles; async collectives are default-on (the flag was
+# removed upstream) and unknown flags make XLA abort at startup, so they
+# are deliberately absent here.  libtpu parses all three too (one XLA flag
+# parser; checked on a v5e), where they configure nothing.
+LATENCY_HIDING_FLAGS = (
+    "--xla_gpu_enable_latency_hiding_scheduler=true",
+    "--xla_gpu_enable_highest_priority_async_stream=true",
+    # CPU backend counterpart: the concurrency-optimized thunk scheduler
+    # overlaps independent thunks (our prefetched chunk copies) on host
+    # platforms, which is what the CI/bench substrate runs on
+    "--xla_cpu_enable_concurrency_optimized_scheduler=true",
+)
 
 
-def latency_hiding_xla_flags(base: str | None = None,
-                             platform: str = "cpu") -> str:
-    """``XLA_FLAGS`` value with ``platform``'s latency-hiding set appended
-    to ``base`` (defaults to the current environment's value);
-    already-present flags are not duplicated."""
+def latency_hiding_xla_flags(base: str | None = None) -> str:
+    """``XLA_FLAGS`` value with the latency-hiding set appended to ``base``
+    (defaults to the current environment's value); already-present flags
+    are not duplicated."""
     flags = (os.environ.get("XLA_FLAGS", "") if base is None else base)
     parts = flags.split()
-    for f in LATENCY_HIDING_FLAGS[platform]:
+    for f in LATENCY_HIDING_FLAGS:
         name = f.split("=", 1)[0]
         if not any(p.split("=", 1)[0] == name for p in parts):
             parts.append(f)
     return " ".join(parts)
 
 
-def overlap_env(env: dict | None = None, enable: bool = True,
-                platform: str = "cpu") -> dict:
-    """A copy of ``env`` (default ``os.environ``) with ``platform``'s
-    latency-hiding flags toggled — the bench/launcher handoff for spawning
-    a fresh process per flag configuration (XLA parses the variable
-    exactly once, and the parent must not touch JAX to find the
-    platform)."""
+def overlap_env(env: dict | None = None, enable: bool = True) -> dict:
+    """A copy of ``env`` (default ``os.environ``) with the latency-hiding
+    flags toggled — the bench/launcher handoff for spawning a fresh process
+    per flag configuration (XLA parses the variable exactly once)."""
     out = dict(os.environ if env is None else env)
     if enable:
-        out["XLA_FLAGS"] = latency_hiding_xla_flags(out.get("XLA_FLAGS", ""),
-                                                    platform)
+        out["XLA_FLAGS"] = latency_hiding_xla_flags(out.get("XLA_FLAGS", ""))
     return out
 
 
