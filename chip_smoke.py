@@ -82,30 +82,25 @@ def emit(**fields) -> None:
     print(json.dumps(fields), flush=True)
 
 
-class CompileClock:
-    """Seconds JAX spends lowering and compiling, summed from its
-    monitoring events, so a phase's wall time splits into compile and
-    run seconds.  Tracing is left on the run side: a jit traced inside
-    another reports a nested span, which would be counted twice."""
+@contextlib.contextmanager
+def timed():
+    """The block's wall seconds split into ``compile_s``, what JAX spent
+    lowering and compiling inside it (``repro.spans``' totals), and
+    ``run_s``, the rest, with the ``lowerings`` (programs lowered) inside
+    it.  Tracing is left on the run side."""
+    from repro import spans
 
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._record)
+    def summed():
+        t = spans.totals().values()
+        return (sum(v["compile_s"] for v in t), sum(v["lowerings"] for v in t))
 
-    def _record(self, event: str, duration: float, **_):
-        if event in ("/jax/core/compile/jaxpr_to_mlir_module_duration",
-                     "/jax/core/compile/backend_compile_duration"):
-            self.seconds += duration
-
-    @contextlib.contextmanager
-    def timed(self):
-        rec: dict = {}
-        c0, t0 = self.seconds, time.perf_counter()
+    rec: dict = {}
+    (c0, l0), t0 = summed(), time.perf_counter()
+    with spans.span("smoke.check"):
         yield rec
-        compile_s = self.seconds - c0
-        rec["compile_s"] = compile_s
-        rec["run_s"] = time.perf_counter() - t0 - compile_s
+    c1, l1 = summed()
+    rec.update(compile_s=c1 - c0, lowerings=l1 - l0)
+    rec["run_s"] = time.perf_counter() - t0 - rec["compile_s"]
 
 
 # --------------------------------------------------------------------------
@@ -176,7 +171,7 @@ def compare_gmm(x, params, got, ref, what):
     return len(diff)
 
 
-def kernels_phase(x_np, k, restarts, *, backend, kind, clock, seed=0):
+def kernels_phase(x_np, k, restarts, *, backend, kind, seed=0):
     import jax
     import jax.numpy as jnp
     from repro.core import EngineConfig
@@ -219,7 +214,7 @@ def kernels_phase(x_np, k, restarts, *, backend, kind, clock, seed=0):
 
         for op, fn, compare in (("kmeans_assign", km, compare_kmeans),
                                 ("gmm_estep", gm, compare_gmm)):
-            with clock.timed() as t:
+            with timed() as t:
                 got = jax.block_until_ready(fn(None))     # auto → chip
             ref = jax.block_until_ready(fn("xla"))
             ties = 0
@@ -239,7 +234,7 @@ def kernels_phase(x_np, k, restarts, *, backend, kind, clock, seed=0):
 # pipeline
 # --------------------------------------------------------------------------
 
-def pipeline_phase(data, argv, *, kind, clock, group_size=GROUP_SIZE,
+def pipeline_phase(data, argv, *, kind, group_size=GROUP_SIZE,
                    train_groups=TRAIN_GROUPS):
     """``repro.launch.cluster``'s pipeline with ``data`` as the one
     production group and ``train_groups`` groups sampled from it:
@@ -251,7 +246,7 @@ def pipeline_phase(data, argv, *, kind, clock, group_size=GROUP_SIZE,
     args = cluster.parse_args(argv)
     train = core.random_groups(data, group_size, max_groups=train_groups,
                                seed=1)
-    with clock.timed() as t:
+    with timed() as t:
         res = cluster.pipeline(args, train, [data])
     h_star = float(res["h_star"])
     it_es, it_fu = res["iters_earlystop"], res["iters_full"]
@@ -280,7 +275,7 @@ def pipeline_phase(data, argv, *, kind, clock, group_size=GROUP_SIZE,
 # serve
 # --------------------------------------------------------------------------
 
-def serve_phase(res, data, k, *, backend, kind, clock, seed=0):
+def serve_phase(res, data, k, *, backend, kind, seed=0):
     import jax
     from repro.core import ClusterArtifact, ClusteringEngine, EngineConfig
     from repro.serving import AssignRequest, ClusterServer, ModelRegistry
@@ -296,13 +291,13 @@ def serve_phase(res, data, k, *, backend, kind, clock, seed=0):
     check(entry.backend == backend,
           f"registry serves through {entry.backend!r}, not {backend!r}")
     server = ClusterServer(registry)
-    with clock.timed() as warm:
+    with timed() as warm:
         server.warmup(key)
     rng = np.random.default_rng(seed)
     sizes = [1, 37, 256, 1000, 3000, 4096, 9000, 16384]
     starts = rng.integers(0, data.shape[0] - max(sizes), len(sizes))
     batches = [data[s:s + m] for s, m in zip(starts, sizes)]
-    with clock.timed() as t:
+    with timed() as t:
         for rid, xb in enumerate(batches):
             server.submit(AssignRequest(x=xb, model_key=key, rid=rid))
         out = server.drain()
@@ -343,7 +338,7 @@ SHARDED_RTOL = {"none": 1e-4, "int8_ef": 1e-2}
 MIN_EM_WEIGHT = 1e-2
 
 
-def sharded_phase(data, k, n_dev, *, kind, clock, restarts=RESTARTS,
+def sharded_phase(data, k, n_dev, *, kind, restarts=RESTARTS,
                   chunks=8, batch_chunks=2, max_iters=MAX_ITERS):
     import jax
     import jax.numpy as jnp
@@ -393,11 +388,11 @@ def sharded_phase(data, k, n_dev, *, kind, clock, restarts=RESTARTS,
                     ("fixed", SHARDED_H_STAR[alg], {}, True),
                     ("fitted", model.threshold_for(DESIRED_ACCURACY),
                      fitted, False)):
-                with clock.timed() as t1:
+                with timed() as t1:
                     _, j1, it1, _ = cluster.run_production(
                         data, k, alg, h, **extra_kw, **kw)
                 for comp in ("none", "int8_ef"):
-                    with clock.timed() as t:
+                    with timed() as t:
                         _, js, its, _, params = cluster.run_production(
                             data, k, alg, h, shard=True,
                             stats_compression=comp, return_params=True,
@@ -453,16 +448,14 @@ def main(argv=None) -> None:
 
     cache = compile_cache.enable()
     kind = dev.device_kind
-    clock = CompileClock()
     emit(phase="start", platform=dev.platform, device_kind=kind,
          devices=n_dev, chips=args.chips, compile_cache=cache)
     poker = load("poker", n=PAPER_SIZES["poker"])
 
     if args.chips == 4:
-        sharded_phase(poker, POKER_K, 4, kind=kind, clock=clock)
+        sharded_phase(poker, POKER_K, 4, kind=kind)
     else:
-        kernels_phase(poker, POKER_K, RESTARTS, backend="tpu", kind=kind,
-                      clock=clock)
+        kernels_phase(poker, POKER_K, RESTARTS, backend="tpu", kind=kind)
         # --family auto: the paper's model-selection comparison (§5.3.1);
         # a pinned quadratic goes negative below r* = 0.99 on these sets
         common = ["--dataset", "poker", "--k", str(POKER_K),
@@ -474,7 +467,7 @@ def main(argv=None) -> None:
                 runs[alg, kernel] = pipeline_phase(
                     poker, common + ["--algorithm", alg]
                     + (["--use-kernel"] if kernel else []),
-                    kind=kind, clock=clock)
+                    kind=kind)
         skin = load("skin", n=PAPER_SIZES["skin"])
         runs["skin"] = pipeline_phase(skin, [
             "--dataset", "skin", "--k", str(SKIN_K), "--mode", "minibatch",
@@ -482,7 +475,7 @@ def main(argv=None) -> None:
             "--restarts", str(RESTARTS), "--use-kernel",
             "--max-iters", str(MAX_ITERS), "--family", "auto",
             "--desired-accuracy", str(DESIRED_ACCURACY)],
-            kind=kind, clock=clock)
+            kind=kind)
         summary = {}
         for _, name, early_stop in runs.values():
             summary.setdefault(early_stop, []).append(name)
@@ -491,7 +484,7 @@ def main(argv=None) -> None:
         check(summary.get("met r*"),
               "no pipeline run's early stop certified r*")
         serve_phase(runs["kmeans", True][0], poker, POKER_K, backend="tpu",
-                    kind=kind, clock=clock)
+                    kind=kind)
 
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": kind,

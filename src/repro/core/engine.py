@@ -63,6 +63,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import spans
+
 from . import em_gmm as _em
 from . import kmeans as _km
 
@@ -1302,7 +1304,7 @@ class ClusteringEngine:
 
     def fit(self, x, params0, h_star=None) -> EngineResult:
         hs = self.config.h_star if h_star is None else h_star
-        with self._tuning():
+        with self._tuning(), spans.span("engine.dispatch"):
             return _fit(jnp.asarray(x), params0,
                         jnp.asarray(hs, jnp.float32),
                         self.algorithm, self.config)
@@ -1318,7 +1320,7 @@ class ClusteringEngine:
                     "fit_restarts needs params0 or (key, k, restarts)")
             params0 = self.init_restarts(key, x, k, restarts)
         hs = self.config.h_star if h_star is None else h_star
-        with self._tuning():
+        with self._tuning(), spans.span("engine.dispatch"):
             return _fit_restarts(x, params0, jnp.asarray(hs, jnp.float32),
                                  self.algorithm, self.config)
 
@@ -1417,7 +1419,7 @@ class ClusteringEngine:
         """
         prog = self.sharded_fit_callable(x, params0, mesh, h_star)
         mask = prog.args[1]
-        with self._tuning():
+        with self._tuning(), spans.span("engine.dispatch"):
             res = prog.fn(*prog.args)
         return res._replace(labels=self._strip_chunk_padding(res.labels,
                                                              mask))
@@ -1472,7 +1474,7 @@ class ClusteringEngine:
         prog = self.sharded_restarts_callable(
             x, params0, mesh, key=key, k=k, restarts=restarts, h_star=h_star)
         mask = prog.args[1]
-        with self._tuning():
+        with self._tuning(), spans.span("engine.dispatch"):
             rr = prog.fn(*prog.args)
         return rr._replace(best=rr.best._replace(
             labels=self._strip_chunk_padding(rr.best.labels, mask)))

@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
+
 from . import em_gmm as _em
 from . import kmeans as _km
 from .earlystop import LongTailModel, fit_longtail
@@ -213,6 +215,7 @@ def reference_partition(plan: TrainingPlan, x, params0) -> jnp.ndarray:
     return eng.fit(x, params0).labels
 
 
+@spans.span("stop.harvest")
 def harvest_traces(plan: TrainingPlan, groups,
                    mesh=None) -> list[tuple[np.ndarray, np.ndarray]]:
     """Run every training group under the plan's (harvest-adjusted)
@@ -275,8 +278,9 @@ def fit_for_config(plan: TrainingPlan, groups, mesh=None,
     provenance so ``EngineConfig.from_longtail`` can police the match."""
     if traces is None:
         traces = harvest_traces(plan, groups, mesh=mesh)
-    return fit_longtail(
-        traces, algorithm=plan.algorithm, dataset=plan.dataset,
-        family=plan.family, balanced=plan.balanced,
-        engine_config=config_fingerprint(
-            plan.config, devices=(mesh.size if mesh is not None else 1)))
+    with spans.span("stop.regression"):
+        return fit_longtail(
+            traces, algorithm=plan.algorithm, dataset=plan.dataset,
+            family=plan.family, balanced=plan.balanced,
+            engine_config=config_fingerprint(
+                plan.config, devices=(mesh.size if mesh is not None else 1)))

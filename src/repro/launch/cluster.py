@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import core
+from repro import core, spans
 from repro.core import em_gmm
 from repro.data import load as load_data, spacenet_pixels
 from repro.launch import compile_cache
@@ -102,6 +102,7 @@ def _resolve_shard(shard: bool, n_devices: int) -> bool:
     return shard
 
 
+@spans.span("entry.job")
 def run_production(x, k: int, algorithm: str, h_star: float, *,
                    max_iters: int, seed: int = 0, shard: bool = False,
                    use_kernel: bool = False, patience: int = 3,
@@ -140,8 +141,8 @@ def run_production(x, k: int, algorithm: str, h_star: float, *,
     the result tuple (for ``--save-artifact``).
     """
     from repro.core.engine import ClusteringEngine, EngineConfig
-    key = jax.random.PRNGKey(seed)
-    x = jnp.asarray(x)
+    with spans.span("entry.transfer"):
+        x = jnp.asarray(x)
 
     shard = _resolve_shard(shard, len(jax.devices()))
     full_reference = (algorithm == "kmeans" and model is None
@@ -164,63 +165,49 @@ def run_production(x, k: int, algorithm: str, h_star: float, *,
         # engine actually samples from it, or every per-group seed would
         # force a fresh full-mode compile
         cfg_kw["seed"] = seed
-    if model is not None:
-        if desired_accuracy is None:
-            raise ValueError("model routing needs desired_accuracy")
-        cfg = EngineConfig.from_longtail(model, desired_accuracy, **cfg_kw)
-    else:
-        cfg = EngineConfig(h_star=float(h_star), **cfg_kw)
+    if model is not None and desired_accuracy is None:
+        raise ValueError("model routing needs desired_accuracy")
+    with spans.span("entry.config"):
+        cfg = (EngineConfig.from_longtail(model, desired_accuracy, **cfg_kw)
+               if model is not None
+               else EngineConfig(h_star=float(h_star), **cfg_kw))
+    eng = ClusteringEngine(algorithm, cfg)
 
-    if restarts > 1:
-        eng = ClusteringEngine(algorithm, cfg)
-        if algorithm == "em":
+    with spans.span("entry.seed"):
+        key = jax.random.PRNGKey(seed)
+        if restarts > 1 and algorithm == "em":
             # match the single-restart init quality: kmeans++-seeded GMMs
             # per restart (the engine default draws uniform data points)
-            keys = jax.random.split(key, restarts)
             inits = [em_gmm.init_from_kmeans(
                 x, core.kmeans_plus_plus_init(kk, x, k, chunks=chunks))
-                for kk in keys]
+                for kk in jax.random.split(key, restarts)]
             params0 = jax.tree.map(lambda *ls: jnp.stack(ls), *inits)
-        else:
+        elif restarts > 1:
             params0 = eng.init_restarts(key, x, k, restarts)
-        t0 = time.time()
-        rr = (eng.fit_restarts_sharded(x, params0, _data_mesh()) if shard
-              else eng.fit_restarts(x, params0))
-        jax.block_until_ready(rr.best.labels)
-        out = (rr.best.labels, float(rr.best.objective),
-               int(rr.best.n_iters), time.time() - t0)
-        return out + (rr.best.params,) if return_params else out
+        else:
+            c0 = core.kmeans_plus_plus_init(key, x, k, chunks=chunks)
+            params0 = (c0 if algorithm == "kmeans"
+                       else em_gmm.init_from_kmeans(x, c0))
 
-    c0 = core.kmeans_plus_plus_init(key, x, k, chunks=chunks)
-    h_star = cfg.h_star
-
-    if shard:
-        # the engine's sharded chunk-layout driver — one path for both
-        # modes AND both sweep implementations: cfg already encodes the
-        # stop semantics (incl. the full_reference frozen-centroids guard
-        # via use_h_stop=False) and the kernel routing (the dispatched ops
-        # take the chunk mask as a weight operand, so the padded layout
-        # streams through Pallas exactly like through jnp), and the padded
-        # layout keeps every row — the label contract matches the
-        # unsharded run.  The old flat shard_map drivers (which truncated
-        # N to a shardable size for use_kernel) are gone.
-        eng = ClusteringEngine(algorithm, cfg)
-        params0 = c0 if algorithm == "kmeans" else em_gmm.init_from_kmeans(
-            x, c0)
-        t0 = time.time()
+    # the fit's seconds (Eq. 10's time): dispatch to labels ready.  The
+    # sharded driver is the engine's chunk-layout one for both modes and
+    # both sweep implementations: cfg already encodes the stop semantics
+    # (incl. the full_reference frozen-centroids guard via
+    # use_h_stop=False) and the kernel routing, and its padded layout
+    # keeps every row, so the label contract matches the unsharded run.
+    t0 = time.perf_counter()
+    if restarts > 1:
+        res = (eng.fit_restarts_sharded(x, params0, _data_mesh()) if shard
+               else eng.fit_restarts(x, params0)).best
+    elif shard:
         res = eng.fit_sharded(x, params0, _data_mesh())
+    else:
+        res = eng.fit(x, params0)
+    with spans.span("entry.wait"):
         jax.block_until_ready(res.labels)
-        out = (res.labels, float(res.objective), int(res.n_iters),
-               time.time() - t0)
-        return out + (res.params,) if return_params else out
-
-    eng = ClusteringEngine(algorithm, cfg)
-    params0 = c0 if algorithm == "kmeans" else em_gmm.init_from_kmeans(x, c0)
-    t0 = time.time()
-    res = eng.fit(x, params0)
-    jax.block_until_ready(res.labels)
-    out = (res.labels, float(res.objective), int(res.n_iters),
-           time.time() - t0)
+    fit_s = time.perf_counter() - t0
+    with spans.span("entry.readback"):
+        out = (res.labels, float(res.objective), int(res.n_iters), fit_s)
     return out + (res.params,) if return_params else out
 
 
