@@ -20,6 +20,7 @@ pass over N/C-sized pieces (see the engine docstring).
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -151,6 +152,7 @@ def _min_d2_scan(xc, mask, c, d2):
     return out
 
 
+@functools.partial(jax.jit, static_argnames=("k", "chunks"))
 def kmeans_plus_plus_init(key, x, k: int, chunks: int = 1):
     """k-means++ seeding (D² sampling), streamed over ``chunks`` pieces.
 
@@ -165,6 +167,14 @@ def kmeans_plus_plus_init(key, x, k: int, chunks: int = 1):
     the same sub-key and is deterministic when C = 1), so ``chunks=1``
     reproduces the flat pass bit-for-bit (property-tested) and existing
     seeds are unchanged.
+
+    One program per (shape and dtype of ``x``, ``k``, ``chunks``): every
+    later call of that shape hits the jit cache and dispatches once.  Run
+    eagerly, the ``lax.scan`` of :func:`_min_d2_scan` and the ``fori_loop``
+    below would each get a body closure built anew on every call, so each
+    call would trace a new jaxpr, miss the cache and lower both programs
+    again (a quarter of a second per job on a v5e host).  The compiled
+    program draws the same points as its eager trace (property-tested).
     """
     x = x.astype(jnp.float32)
     n = x.shape[0]

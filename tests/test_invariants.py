@@ -1,7 +1,8 @@
 """Property-based early-stop invariants (ISSUE 1): objective monotonicity,
 change-rate scale invariance, LongTailModel persistence round-trip; plus
 streamed k-means++ invariants (ISSUE 2): k distinct in-bounds picks under
-any chunking, and exact chunks=1 equivalence with the monolithic pass.
+any chunking, exact chunks=1 equivalence with the monolithic pass, and
+the compiled seeding equal to its eager trace.
 
 Runs under real hypothesis when installed, or under the seeded
 mini-hypothesis shim in conftest.py on a bare JAX install.
@@ -114,6 +115,24 @@ def test_streamed_kmeanspp_chunks1_equals_monolithic_exactly(seed, k):
     a = _monolithic_kmeans_pp(key, x, k)
     b = core.kmeans_plus_plus_init(key, x, k, chunks=1)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("chunks", [1, 3])
+@given(seed=st.integers(0, 10_000), k=st.integers(2, 6))
+@settings(max_examples=6, deadline=None)
+def test_compiled_kmeanspp_equals_its_eager_trace(chunks, seed, k):
+    """The seeding is one jitted program per shape; run op by op (its
+    undecorated body with jit off, so the scan and the loop run in Python)
+    it must pick the same points bit-for-bit, for a chunking that divides
+    nothing as well as for the flat pass."""
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(0, 5.0, (257, 4)).astype(np.float32))
+    key = jax.random.PRNGKey(seed)
+    compiled = core.kmeans_plus_plus_init(key, x, k, chunks=chunks)
+    with jax.disable_jit():
+        eager = core.kmeans_plus_plus_init.__wrapped__(key, x, k,
+                                                       chunks=chunks)
+    np.testing.assert_array_equal(np.asarray(compiled), np.asarray(eager))
 
 
 @given(seed=st.integers(0, 99), a=st.floats(0.5, 3.0))

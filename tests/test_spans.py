@@ -182,6 +182,26 @@ def test_run_production_opens_each_entry_span_once(branch, opened):
         assert inside <= d["entry.job"]["seconds"]
 
 
+def test_seeding_lowers_once_per_shape_k_and_chunks():
+    """A job of a shape already seen seeds from the compiled program: no
+    lowering under ``entry.seed``.  A new ``k``, ``chunks`` or N is a new
+    program and lowers once."""
+    x = np.random.default_rng(3).normal(size=(1792, 3)).astype(np.float32)
+
+    def seed_lowerings(x, k, seed, chunks=1):
+        before = spans.totals()
+        run_production(x, k, "kmeans", 1e-3, max_iters=8, seed=seed,
+                       chunks=chunks)
+        return delta(before, spans.totals())["entry.seed"]["lowerings"]
+
+    assert seed_lowerings(x, 3, 0) >= 1
+    assert seed_lowerings(x, 3, 1) == 0
+    assert seed_lowerings(x, 3, 2) == 0
+    assert seed_lowerings(x, 4, 3) >= 1
+    assert seed_lowerings(x, 3, 4, chunks=2) >= 1
+    assert seed_lowerings(x[:1791], 3, 5) >= 1
+
+
 def test_the_stop_model_fit_opens_harvest_and_regression(opened):
     rng = np.random.default_rng(1)
     groups = [np.concatenate([rng.normal(0, 1, (300, 2)),
