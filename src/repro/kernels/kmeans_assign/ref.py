@@ -4,6 +4,12 @@
 registered ``xla`` backend delegates here (so the test oracle and the
 backend users run with ``kernel_backend="xla"`` cannot drift), and the
 historical ``kmeans_assign_ref`` signature wraps it with unit weights.
+
+It deliberately keeps the scatter-add by label for ``sums``/``counts``:
+the default jnp sweep (``repro.core.kmeans.assign_and_stats``) forms them
+as a one-hot MXU contraction, so this oracle checks that path by an
+independent computation.  The ``xla`` backend therefore keeps the
+scatter's cost: on a TPU the scatter serialises its colliding updates.
 """
 from __future__ import annotations
 
