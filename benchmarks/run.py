@@ -1,5 +1,5 @@
 """Benchmark harness — one function per paper table/figure + kernel
-microbenches + the roofline table (reads the dry-run JSONs).
+microbenches.
 
     PYTHONPATH=src python -m benchmarks.run              # everything
     PYTHONPATH=src python -m benchmarks.run --only fig7,table2
@@ -9,7 +9,6 @@ Output: CSV rows to stdout (name,metric,value,…) and benchmarks/out/*.csv.
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import time
@@ -266,7 +265,6 @@ def kernels():
     import jax.numpy as jnp
     from repro.kernels.kmeans_assign.ref import kmeans_assign_ref
     from repro.kernels.gmm_estep.ref import gmm_estep_ref
-    from repro.models.layers import _sdpa, _sdpa_chunked
 
     rng = np.random.default_rng(0)
     rows = []
@@ -292,19 +290,6 @@ def kernels():
                  "us_per_call": round(us, 1),
                  "derived": f"{3 * flops / us * 1e-3:.2f}GFLOPs"})
 
-    q = jnp.asarray(rng.normal(0, 1, (1, 2048, 8, 64)).astype(np.float32))
-    k = jnp.asarray(rng.normal(0, 1, (1, 2048, 2, 64)).astype(np.float32))
-    v = jnp.asarray(rng.normal(0, 1, (1, 2048, 2, 64)).astype(np.float32))
-    f_exact = jax.jit(lambda q, k, v: _sdpa(q, k, v, causal=True, window=None))
-    f_chunk = jax.jit(lambda q, k, v: _sdpa_chunked(q, k, v, causal=True,
-                                                    window=None))
-    us_e = timeit(f_exact, q, k, v, n=3)
-    us_c = timeit(f_chunk, q, k, v, n=3)
-    rows.append({"name": "attention_exact_s2048", "us_per_call": round(us_e, 1),
-                 "derived": "materialises SxS"})
-    rows.append({"name": "attention_chunked_s2048",
-                 "us_per_call": round(us_c, 1),
-                 "derived": f"{us_e / us_c:.2f}x_vs_exact_O(S)_mem"})
     return rows
 
 
@@ -1056,85 +1041,6 @@ def serve_cluster():
         json.dump(payload, f, indent=1)
         f.write("\n")
     print(f"# wrote {path}")
-    return rows
-
-
-# --------------------------------------------------------------------------
-# Roofline table (reads experiments/dryrun/*.json → §Roofline source data)
-# --------------------------------------------------------------------------
-
-@bench("roofline_table")
-def roofline_table():
-    rows = []
-    src = next(d for d in ("experiments/dryrun_v3", "experiments/dryrun_v2",
-                           "experiments/dryrun")
-               if glob.glob(d + "/*.json"))
-    for path in sorted(glob.glob(f"{src}/*.json")):
-        with open(path) as f:
-            d = json.load(f)
-        if "error" in d:
-            rows.append({"cell": os.path.basename(path)[:-5], "status": "ERROR",
-                         "compute_s": "", "memory_s": "", "collective_s": "",
-                         "dominant": "", "useful_ratio": "", "hbm_gib": ""})
-            continue
-        r = d["roofline"]
-        mem = d["memory"]
-        hbm = (mem["argument_size_in_bytes"] + mem["temp_size_in_bytes"]
-               + mem["output_size_in_bytes"] - mem["alias_size_in_bytes"])
-        rows.append({
-            "cell": f"{d['arch']}__{d['shape']}__{d['mesh']}",
-            "status": "OK",
-            "compute_s": round(r["compute_s"], 4),
-            "memory_s": round(r["memory_s"], 4),
-            "collective_s": round(r["collective_s"], 4),
-            "dominant": r["dominant"],
-            "useful_ratio": round(r["useful_ratio"], 3),
-            "hbm_gib": round(hbm / 2**30, 2),
-        })
-    return rows
-
-
-@bench("perf_compare")
-def perf_compare():
-    """§Perf table: baseline (dryrun_v3, optimizations off) vs optimized
-    (perf_v3) under the same trip-count-aware cost model."""
-    cells = [
-        ("xlstm-350m__train_4k", "chunkwise mLSTM L=128"),
-        ("qwen3-moe-30b-a3b__prefill_32k", "grouped dispatch G=16"),
-        ("gemma3-12b__decode_32k", "ring window caches"),
-    ]
-    rows = []
-    for cell, change in cells:
-        for mesh in ("16x16", "pod2x16x16"):
-            try:
-                def first(*paths):
-                    for q in paths:
-                        if os.path.exists(q):
-                            with open(q) as f:
-                                return json.load(f)
-                    raise FileNotFoundError(paths)
-                b = first(f"experiments/dryrun_v4/{cell}__{mesh}.json",
-                          f"experiments/dryrun_v3/{cell}__{mesh}.json")
-                o = first(f"experiments/perf_v4/{cell}__{mesh}.json",
-                          f"experiments/perf_v3/{cell}__{mesh}.json")
-            except FileNotFoundError:
-                continue
-            br, orr = b["roofline"], o["roofline"]
-            bm = b["memory"]; om = o["memory"]
-            gib = lambda m: (m["argument_size_in_bytes"] + m["temp_size_in_bytes"]
-                             + m["output_size_in_bytes"]
-                             - m["alias_size_in_bytes"]) / 2**30
-            dom = br["dominant"] + "_s"
-            rows.append({
-                "cell": f"{cell}__{mesh}", "change": change,
-                "dominant": br["dominant"],
-                "before_s": round(br[dom], 4), "after_s": round(orr[dom], 4),
-                "speedup": round(br[dom] / max(orr[dom], 1e-9), 1),
-                "mem_gib_before": round(gib(bm), 1),
-                "mem_gib_after": round(gib(om), 1),
-                "useful_before": round(br["useful_ratio"], 3),
-                "useful_after": round(orr["useful_ratio"], 3),
-            })
     return rows
 
 

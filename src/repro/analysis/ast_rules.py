@@ -6,8 +6,8 @@ layer, before tracing):
   AST001  kernel entry points in ``kernels/*/ops.py`` whose first
           parameter is the points array ``x`` must accept ``mask=`` —
           padding, sharding and minibatch draws all compose through the
-          mask operand, on every backend (``flash_attention``'s ``q``
-          leading parameter is naturally exempt);
+          mask operand, on every backend (helpers such as
+          ``chunk_bounds(n, chunks)`` lead with no ``x`` and are exempt);
   AST002  collective calls must not hard-code axis names as string
           literals — graphs take the axis from config/mesh so one
           program serves every mesh layout (warning severity: literal
@@ -199,7 +199,7 @@ def _check_rng_in_traced(tree: ast.Module, relpath: str,
 
 # ------------------------------------------------------------------ AST004
 
-_BLOCK_KWARGS = frozenset({"block_n", "block_q", "block_k", "block_rows"})
+_BLOCK_KWARGS = frozenset({"block_n", "block_rows"})
 
 
 def _check_block_literals(tree: ast.Module, relpath: str,

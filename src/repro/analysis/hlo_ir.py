@@ -2,8 +2,8 @@
 
 Promoted from ``launch/hlo_cost.py`` (ISSUE 8) so the roofline cost model
 and the static-analysis rules parse compiled modules through ONE parser
-instead of three private regex copies (``hlo_cost``, ``hlo_analysis`` and
-the bench scripts each had their own).  ``launch/hlo_cost.py`` re-exports
+instead of private regex copies (``hlo_cost`` and the bench scripts each
+had their own).  ``launch/hlo_cost.py`` re-exports
 everything under its historical names.
 
 The model: ``compiled.as_text()`` is parsed into ``{computation name:

@@ -74,7 +74,7 @@ RULE_CATALOGUE: dict[str, tuple[str, str, str]] = {
     "AST004": (
         "hardcoded-block-shape", "error",
         "kernel call sites must not hard-code integer block shapes "
-        "(block_n/block_q/block_k/block_rows) — blocks resolve through "
+        "(block_n/block_rows) — blocks resolve through "
         "layout.tile_policy() and the autotune cache; TilePolicy "
         "constructors (the defaults themselves) are exempt"),
 }

@@ -7,8 +7,7 @@ from .rand_index import (rand_index, adjusted_rand_index, contingency_table,
                          rand_index_from_contingency, sharded_contingency)
 from .regression import (RegressionModel, FitMetrics, fit_family, select_model,
                          pool_traces, rh_from_objectives, FAMILIES)
-from .earlystop import (LongTailModel, EarlyStopHook, fit_longtail,
-                        change_rate, harvest_lm_trace)
+from .earlystop import LongTailModel, fit_longtail, change_rate
 from .longtail_train import (TrainingPlan, config_fingerprint, harvest_config,
                              harvest_traces, fit_for_config)
 from .kmeans import (kmeans_step, kmeans_fit_traced, kmeans_fit_earlystop,

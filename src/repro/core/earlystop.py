@@ -7,11 +7,9 @@ h_i = |J_i − J_{i−1}|/|J_{i−1}| ≤ h*  (Eq. 7, §4).  The dollars that st
 saves are accounted by ``cost_model`` (Eq. 6/9/10); the provisioning
 planner (``core.planner``) turns h* into a predicted stop iteration.
 
-Two consumers:
-  · the distributed clustering engine — the predicate runs **on device**
-    inside ``jax.lax.while_loop`` (no host round-trip per iteration);
-  · the LM training loop (beyond-paper generalisation) — ``EarlyStopHook``
-    EMA-smooths the noisy SGD loss before applying the same rule.
+The consumer is the clustering engine (``core.engine``): the predicate runs
+**on device** inside ``jax.lax.while_loop``, with no host round-trip per
+iteration.
 """
 from __future__ import annotations
 
@@ -43,7 +41,7 @@ class LongTailModel:
     config and warns loudly on a mismatch.  ``None`` marks a legacy /
     externally-harvested fit with no stamped regime (no warning)."""
     regression: RegressionModel
-    algorithm: str                  # "kmeans" | "em" | "lm_train" | ...
+    algorithm: str                  # "kmeans" | "em"
     dataset: str
     n_train_groups: int
     comparison: dict | None = None  # {family: FitMetrics} from model selection
@@ -113,76 +111,3 @@ def fit_longtail(traces: Sequence[tuple[np.ndarray, np.ndarray]], *,
                          n_train_groups=len(traces), comparison=table,
                          engine_config=engine_config)
 
-
-def harvest_lm_trace(losses, ema: float = 0.95):
-    """(r, h) pairs from a pilot run's loss curve, using EXACTLY the EMA
-    recurrence EarlyStopHook applies online — so the fitted threshold lives
-    on the same scale the hook will compare against.
-
-    r_i = (s₀ − s_i) / (s₀ − s_final): relative progress of the smoothed
-    objective toward its final value (the LM analogue of Rand accuracy).
-    """
-    losses = np.asarray(losses, np.float64)
-    s = np.empty_like(losses)
-    s[0] = losses[0]
-    for i in range(1, losses.size):
-        s[i] = ema * s[i - 1] + (1 - ema) * losses[i]
-    # Eq. 7 anchored at J₀ instead of J_{i−1}: CE losses converge toward ~0,
-    # where the relative-to-current rate stays constant under exponential
-    # decay and never signals the tail.  Anchoring keeps h ↓ 0 as absolute
-    # progress stalls (documented LM adaptation, DESIGN.md §2).
-    h = np.abs(np.diff(s)) / max(abs(s[0]), 1e-30)
-    denom = max(s[0] - s[-1], 1e-9)
-    r = np.clip((s[0] - s[1:]) / denom, 0.0, 1.0)
-    return r, h
-
-
-class EarlyStopHook:
-    """Host-side controller for noisy iterative objectives (LM training).
-
-    SGD loss is not monotone per step, so the raw Eq. 7 signal is useless at
-    step granularity.  We EMA both the objective and its change rate and
-    require ``patience`` consecutive sub-threshold readings — a documented
-    deviation from the paper (DESIGN.md §2), needed for the generalisation.
-    """
-
-    def __init__(self, model: LongTailModel, desired_accuracy: float,
-                 ema: float = 0.98, patience: int = 5, min_steps: int = 20,
-                 require_arming: bool = True):
-        self.h_star = model.threshold_for(desired_accuracy)
-        self.desired_accuracy = desired_accuracy
-        self.ema = ema
-        self.patience = patience
-        self.min_steps = min_steps
-        # arming: the h signal must first EXCEED h* (i.e. training must be
-        # visibly improving) before sub-threshold readings count — prevents
-        # spurious stops during the flat warmup phase where h starts near 0.
-        self.require_arming = require_arming
-        self._armed = not require_arming
-        self._smoothed = None
-        self._prev = None
-        self._anchor = None   # J₀ — see harvest_lm_trace on why not J_{i−1}
-        self._hits = 0
-        self.step = 0
-        self.history: list[tuple[int, float, float]] = []  # (step, J_ema, h)
-
-    def update(self, objective: float) -> bool:
-        """Feed one objective reading; returns True when training should stop."""
-        self.step += 1
-        obj = float(objective)
-        self._smoothed = obj if self._smoothed is None else (
-            self.ema * self._smoothed + (1 - self.ema) * obj)
-        if self._prev is None:
-            self._prev = self._smoothed
-            self._anchor = max(abs(self._smoothed), 1e-30)
-            return False
-        h = abs(self._smoothed - self._prev) / self._anchor
-        self._prev = self._smoothed
-        self.history.append((self.step, self._smoothed, h))
-        if not self._armed:
-            self._armed = h > self.h_star
-            return False
-        if self.step < self.min_steps:
-            return False
-        self._hits = self._hits + 1 if h <= self.h_star else 0
-        return self._hits >= self.patience

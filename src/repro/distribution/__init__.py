@@ -1,1 +1,2 @@
-from .hints import ShardingRules, use_rules, hint, current_rules
+"""Point sharding (``sharding``) and the int8 error-feedback ring for the
+sharded drivers' statistics (``compression``)."""

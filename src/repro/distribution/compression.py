@@ -1,5 +1,5 @@
-"""int8 gradient all-reduce with error feedback (beyond-paper distributed-
-optimization trick, DESIGN.md §4).
+"""int8 all-reduce with error feedback for the sharded drivers' per-sweep
+sufficient statistics (``EngineConfig(stats_compression="int8_ef")``).
 
 Wire-format compression needs the reduction implemented manually — a plain
 ``psum(int8)`` would still move int32 on the wire after XLA's accumulation-
@@ -9,9 +9,9 @@ int8 chunks only → 4× collective-byte reduction vs f32 psum (2× vs bf16).
 
 Quantisation: a shared scale = pmax(|g|)/127, one per row of a matrix leaf
 (per cluster for the engine's [K, D] moments) and one per vector leaf,
-stochastic-free symmetric rounding.  ``ErrorFeedback`` carries
-the per-leaf quantisation residual into the next step (Karimireddy et al.
-2019 — keeps SGD convergence despite biased rounding).
+stochastic-free symmetric rounding.  :func:`compress_with_feedback` carries
+the per-leaf quantisation residual into the next sweep (error feedback,
+Karimireddy et al. 2019), so the rounding bias does not accumulate.
 
 Used under ``shard_map`` on the DP axes; validated numerically in
 tests/test_distribution.py (ring semantics on the 8-host-device ``mesh8``
@@ -123,7 +123,7 @@ def init_error_feedback(params):
     return jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
 
 
-def compress_with_feedback(grads, ef_state, reduce_fn, scale_fn=None):
+def compress_with_feedback(grads, ef_state, reduce_fn, scale_fn):
     """g' = reduce(g + e);  e ← (g + e) − dequant-path(g + e).
 
     ``reduce_fn(leaf)`` performs the lossy reduction (e.g. ring int8).  The
@@ -132,13 +132,8 @@ def compress_with_feedback(grads, ef_state, reduce_fn, scale_fn=None):
     quantises with — when ``reduce_fn`` is ``ring_allreduce_int8`` that is
     ``shared_scale`` (pmax × axis_size), NOT the local ``max(|leaf|)/127``:
     with the wrong scale the residual models rounding that never happened
-    and the EF buffer absorbs the wrong error.  Defaults to the local scale
-    for the single-device ``fake_quantize_grads`` path, where the two
-    coincide.
+    and the EF buffer absorbs the wrong error.
     """
-    if scale_fn is None:
-        scale_fn = lambda g: jnp.maximum(jnp.max(jnp.abs(g)), 1e-12) / 127.0
-
     def one(g, e):
         corrected = g.astype(jnp.float32) + e
         reduced = reduce_fn(corrected)
@@ -166,11 +161,3 @@ def ring_wire_bytes(payload_bytes: int, axis_size: int) -> int:
         return 0
     return int(2 * (axis_size - 1) * payload_bytes) // int(axis_size)
 
-
-def fake_quantize_grads(grads):
-    """Single-device numerical model of the compressed all-reduce (tests &
-    single-host training): quantise→dequantise each leaf with its own scale."""
-    def one(g):
-        scale = jnp.maximum(jnp.max(jnp.abs(g)), 1e-12) / 127.0
-        return dequantize_int8(quantize_int8(g.astype(jnp.float32), scale), scale)
-    return jax.tree.map(one, grads)

@@ -1,73 +1,25 @@
-"""Sharding rules: logical-axis → PartitionSpec for params, activations,
-inputs and caches, per mesh.
+"""Sharding rules for clustering point sets, per mesh.
 
-Mesh axes: ``("pod", "data", "model")`` multi-pod, ``("data", "model")``
-single-pod.  Policy (DESIGN.md §4):
+The engine's data-parallel drivers shard the *points* and replicate the
+parameters: every shard computes its local [K, D] + [K] + [1] sufficient
+statistics and one psum per sweep combines them.  Two layouts:
 
-  · batch            → ("pod", "data")      — pure DP across pods (pods talk
-                                              only for gradient all-reduce)
-  · heads/ffn/vocab/experts → "model"       — tensor/expert parallel inside a pod
-  · params' other large axis → "data"       — FSDP (never across pods)
-  · decode KV caches → sequence over "model" (flash-decode style partial
-    softmax), batch over DP axes; long_500k (batch=1) shards sequence over
-    ("data","model") and recurrent-state feature axes over "model".
+  · [N, D] flat points (:func:`points_spec`, :func:`shard_points`): N over
+    the data axes, D replicated; N truncated to a multiple of the extent.
+  · [C, N/C, D] pre-chunked points (:func:`chunked_points_spec`,
+    :func:`shard_chunked_points`): the chunk axis replicated, rows within a
+    chunk over the data axes, padded rows carrying mask 0.  This is the
+    layout of the sharded full, minibatch and restart drivers.
 
-Param specs are derived from leaf *paths* (module naming is the contract);
-leaves under "blocks" carry a leading stacked-period axis → specs get a
-leading None.
+The data axes are ``("pod", "data")``, whichever the mesh has.  On a mesh
+with neither, the specs replicate the points, and the engine's sharded
+drivers refuse the mesh.
 """
 from __future__ import annotations
-
-import re
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .hints import ShardingRules
-
-# path-regex → spec builder (dp = FSDP axis name or None, tp = "model")
-# Applied in order; first match wins. Specs are for the UNSTACKED leaf.
-_PARAM_RULES: list[tuple[str, tuple]] = [
-    (r"embed$",                    ("tp", "dp")),       # [V, D]
-    (r"head$",                     ("dp", "tp")),       # [D, V]
-    (r"attn/(wq|wk|wv)$",          ("dp", "tp")),       # [D, H·dh]
-    (r"attn/wo$",                  ("tp", "dp")),       # [H·dh, D]
-    (r"attn/(bq|bk|bv)$",          ("tp",)),
-    (r"(mlp|shared)/(w_gate|w_up)$", ("dp", "tp")),     # [D, F]
-    (r"(mlp|shared)/w_down$",      ("tp", "dp")),       # [F, D]
-    (r"moe/router$",               (None, None)),
-    (r"moe/(w_gate|w_up)$",        ("tp", "dp", None)), # [E, D, F]
-    (r"moe/w_down$",               ("tp", None, "dp")), # [E, F, D]
-    (r"mamba/in_proj$",            ("dp", "tp")),
-    (r"mamba/out_proj$",           ("tp", "dp")),
-    (r"mamba/conv_w$",             (None, "tp")),
-    (r"mamba/x_proj$",             ("tp", None)),
-    (r"mamba/dt_w$",               (None, "tp")),
-    (r"mamba/(dt_b|D)$",           ("tp",)),
-    (r"mamba/A_log$",              ("tp", None)),
-    (r"cell/up$",                  ("dp", "tp")),
-    (r"cell/(wq|wk|wv)$",          (None, "tp")),
-    (r"cell/down$",                ("tp", "dp")),
-    (r"cell/(wi|wf)$",             ("tp", None)),
-    (r"cell/w$",                   ("dp", "tp")),       # slstm in-proj
-    (r"cell/r$",                   (None, None, None)), # block-diag, small
-    (r"cell/(ff_gate|ff_up)$",     ("dp", "tp")),
-    (r"cell/ff_down$",             ("tp", "dp")),
-    (r"cell/gnorm$",               ("tp",)),
-]
-
-
-def _path_str(path) -> str:
-    parts = []
-    for p in path:
-        if hasattr(p, "key"):
-            parts.append(str(p.key))
-        elif hasattr(p, "idx"):
-            parts.append(str(p.idx))
-        else:
-            parts.append(str(p))
-    return "/".join(parts)
 
 
 def mesh_axes(mesh: Mesh) -> tuple[tuple[str, ...], str | None, str | None]:
@@ -79,32 +31,6 @@ def mesh_axes(mesh: Mesh) -> tuple[tuple[str, ...], str | None, str | None]:
     return dp, fsdp, tp
 
 
-def param_spec(path, leaf, mesh: Mesh, *, fsdp: bool = True) -> P:
-    """PartitionSpec for one param leaf (handles the stacked-period axis)."""
-    _, fsdp_axis, tp_axis = mesh_axes(mesh)
-    if not fsdp:
-        fsdp_axis = None
-    s = _path_str(path)
-    stacked = s.startswith("blocks")
-    shape = leaf.shape[1:] if stacked else leaf.shape
-    spec: tuple = ()
-    for pat, axes in _PARAM_RULES:
-        if re.search(pat, s):
-            spec = tuple({"dp": fsdp_axis, "tp": tp_axis, None: None}[a]
-                         for a in axes)
-            break
-    if len(spec) != len(shape):       # norms/scales/unmatched → replicate
-        spec = (None,) * len(shape)
-    # divisibility guard: drop axes that don't divide evenly (GSPMD would
-    # pad; we prefer the predictable layout)
-    spec = tuple(
-        ax if (ax is not None and shape[i] % _axis_size(mesh, ax) == 0) else None
-        for i, ax in enumerate(spec))
-    if stacked:
-        spec = (None,) + spec
-    return P(*spec)
-
-
 def _axis_size(mesh: Mesh, ax) -> int:
     if ax is None:
         return 1
@@ -112,18 +38,6 @@ def _axis_size(mesh: Mesh, ax) -> int:
         return int(np.prod([mesh.shape[a] for a in ax]))
     return mesh.shape[ax]
 
-
-def param_shardings(params_struct, mesh: Mesh, *, fsdp: bool = True):
-    """NamedSharding pytree matching an eval_shape'd params structure."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: NamedSharding(mesh, param_spec(path, leaf, mesh,
-                                                          fsdp=fsdp)),
-        params_struct)
-
-
-# --------------------------------------------------------------------------
-# Clustering point sets (ClusteringEngine data-parallel path)
-# --------------------------------------------------------------------------
 
 def points_spec(mesh: Mesh) -> P:
     """[N, D] clustering points: N over the data axes, D replicated — the
@@ -189,91 +103,3 @@ def shard_chunked_points(xc, mask, mesh: Mesh):
     ms = jax.device_put(jax.numpy.asarray(mask),
                         NamedSharding(mesh, P(*tuple(spec)[:2])))
     return xs, ms
-
-
-# --------------------------------------------------------------------------
-# Activation hint rules
-# --------------------------------------------------------------------------
-
-def activation_rules(mesh: Mesh, *, batch_shardable: bool = True) -> ShardingRules:
-    dp, _, tp = mesh_axes(mesh)
-    b = dp if (dp and batch_shardable) else None
-    return ShardingRules({
-        "act_btd":    P(b, None, None),
-        "act_bshd":   P(b, None, tp, None),
-        "act_btf":    P(b, None, tp),
-        "logits_btv": P(b, None, tp),
-        "moe_ecd":    P(tp, None, None),
-        "moe_ecf":    P(tp, None, None),
-        # grouped dispatch (hillclimb #2): groups ride the DP axes; the
-        # ep-layout hints trigger the buffer all-to-all into expert parallel
-        "moe_gtd":     P(b, None, None),
-        "moe_gecd_dp": P(b, None, None, None),
-        "moe_gecd_ep": P(None, tp, None, None),
-        "moe_gecf_ep": P(None, tp, None, None),
-    })
-
-
-# --------------------------------------------------------------------------
-# Input / cache shardings per (arch × shape)
-# --------------------------------------------------------------------------
-
-def _largest_divisible_axis(shape, sizes_needed: int, skip=()):
-    order = sorted(range(len(shape)), key=lambda i: -shape[i])
-    for i in order:
-        if i not in skip and shape[i] % sizes_needed == 0 and shape[i] >= sizes_needed:
-            return i
-    return None
-
-
-def cache_spec(path, leaf, mesh: Mesh, batch: int) -> P:
-    """Decode-cache leaf spec.  Leaves are [P, B, ...] stacks."""
-    dp, _, tp = mesh_axes(mesh)
-    s = _path_str(path)
-    shape = leaf.shape
-    dp_size = _axis_size(mesh, dp) if dp else 1
-    spec = [None] * len(shape)
-    if dp and batch % dp_size == 0 and batch >= dp_size:
-        spec[1] = dp
-        # K/V: seq over model; states: feature axis over model
-        if tp:
-            if re.search(r"/(k|v)$", s):
-                if shape[2] % mesh.shape[tp] == 0:
-                    spec[2] = tp            # sequence (flash-decode)
-            else:
-                i = _largest_divisible_axis(shape, mesh.shape[tp], skip=(0, 1))
-                if i is not None:
-                    spec[i] = tp
-    else:
-        # batch=1 (long_500k): spread sequence/feature over everything
-        combo = tuple(a for a in (("data",) if "data" in mesh.axis_names else ())
-                      ) + ((tp,) if tp else ())
-        combo = tuple(a for a in ("data", "model") if a in mesh.axis_names)
-        if re.search(r"/(k|v)$", s) and combo:
-            n = _axis_size(mesh, combo)
-            if shape[2] % n == 0:
-                spec[2] = combo
-        elif tp:
-            i = _largest_divisible_axis(shape, mesh.shape[tp], skip=(0, 1))
-            if i is not None:
-                spec[i] = tp
-    return P(*spec)
-
-
-def input_shardings(specs: dict, mesh: Mesh, batch: int):
-    """NamedSharding pytree for an ``input_specs`` dict (any shape kind)."""
-    dp, _, tp = mesh_axes(mesh)
-    dp_size = _axis_size(mesh, dp) if dp else 1
-    batch_ok = dp and batch % dp_size == 0 and batch >= dp_size
-
-    def one(path, leaf):
-        s = _path_str(path)
-        if s.startswith("caches"):
-            return NamedSharding(mesh, cache_spec(path, leaf, mesh, batch))
-        if leaf.ndim == 0:
-            return NamedSharding(mesh, P())
-        if batch_ok:
-            return NamedSharding(mesh, P(dp, *([None] * (leaf.ndim - 1))))
-        return NamedSharding(mesh, P(*([None] * leaf.ndim)))
-
-    return jax.tree_util.tree_map_with_path(one, specs)

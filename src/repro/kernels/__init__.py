@@ -3,13 +3,12 @@ backend-dispatching layer (``dispatch.py`` + ``layout.py``).
 
 kmeans_assign  — fused k-means assignment + statistics (paper's inner loop)
 gmm_estep      — fused diagonal-GMM E-step + M-step sufficient statistics
-flash_attention— GQA flash attention (causal / sliding-window / bidirectional)
 
-Each package: kernel.py (pl.pallas_call + BlockSpec, restart-axis grid for
-the clustering ops), ops.py (public wrapper: per-backend padding +
-registry dispatch), ref.py (pure-jnp oracle for tests).  Registered
-backends per op: ``tpu`` (Mosaic-compiled), ``gpu`` (Triton lowering, GPU
-tile policy), ``interpret`` (same kernel under the Pallas interpreter —
-the CPU CI path), ``xla`` (reference contract).  ``dispatch.force_backend``
-/ ``register_backend`` let tests pin or extend any path.
+Each package: kernel.py (pl.pallas_call + BlockSpec, restart-axis grid),
+ops.py (public wrapper: per-backend padding + registry dispatch), ref.py
+(pure-jnp oracle for tests).  Registered backends per op: ``tpu``
+(Mosaic-compiled), ``gpu`` (Triton lowering, GPU tile policy),
+``interpret`` (same kernel under the Pallas interpreter — the CPU CI
+path), ``xla`` (reference contract).  ``dispatch.force_backend`` /
+``register_backend`` let tests pin or extend any path.
 """

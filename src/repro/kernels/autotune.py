@@ -14,8 +14,8 @@ bucket)``.
 
 Resolution contract (the ops consult :func:`tuned_blocks`):
 
-  · an explicit ``block_n=`` / ``block_q=`` / ``block_k=`` argument
-    always wins — the cache is never consulted;
+  · an explicit ``block_n=`` argument always wins — the cache is never
+    consulted;
   · no active cache (or no matching entry) → the hand-picked
     ``TilePolicy`` defaults, bit-for-bit unchanged;
   · an active cache entry supplies the blocks, which still pass through
@@ -53,22 +53,18 @@ from repro.kernels.timing import time_callable
 
 SCHEMA_VERSION = 1
 
-# ops this tuner knows how to drive (shape triple semantics per op:
-# clustering = rows × clusters × features; flash = Sq × Skv × head_dim)
-SUPPORTED_OPS = ("kmeans_assign", "gmm_estep", "flash_attention")
+# ops this tuner knows how to drive (shape triple: rows × clusters ×
+# features)
+SUPPORTED_OPS = ("kmeans_assign", "gmm_estep")
 
 # row-block candidate grid; each entry passes through TilePolicy.block_for
 # so alignment (incl. the Triton pow2 rule) and the n-cap are enforced
 ROW_BLOCK_GRID = (128, 256, 512, 1024, 2048)
-FLASH_BLOCK_GRID = (64, 128, 256)
 
 DEFAULT_SHAPES: dict[str, tuple[tuple[int, int, int], ...]] = {
     "kmeans_assign": ((16384, 8, 16), (65536, 8, 4)),
     "gmm_estep": ((16384, 8, 16),),
-    "flash_attention": ((512, 512, 64),),
 }
-
-_FLASH_HEADS = 2  # fixed head count for flash sweep operands (B=1)
 
 
 class StaleCacheError(ValueError):
@@ -232,11 +228,7 @@ def default_blocks(op: str, backend: str, *, n: int, k: int, d: int) -> dict:
     """The hand-picked blocks the op resolves without any cache — the
     sweep's baseline candidate (kept bit-for-bit in sync with the ops'
     own no-override resolution)."""
-    pol = layout.tile_policy(backend)
-    if op == "flash_attention":
-        return {"block_q": min(128, layout.round_up(n, pol.row_align)),
-                "block_k": min(128, layout.round_up(k, pol.row_align))}
-    return {"block_n": pol.block_for(n)}
+    return {"block_n": layout.tile_policy(backend).block_for(n)}
 
 
 def candidate_blocks(op: str, backend: str, *, n: int, k: int,
@@ -244,7 +236,7 @@ def candidate_blocks(op: str, backend: str, *, n: int, k: int,
     """Deterministic candidate list, default first, duplicates removed.
 
     Every candidate is passed through the backend's ``TilePolicy``
-    alignment (``block_for`` / ``round_up``), so the grid can never
+    alignment (``block_for``), so the grid can never
     propose a block the lowering rejects — including Triton's pow2 rule.
     The ``xla`` reference ignores block shapes entirely, so it gets the
     single default candidate (a sweep there would time one program five
@@ -263,14 +255,8 @@ def candidate_blocks(op: str, backend: str, *, n: int, k: int,
             cands.append(blocks)
 
     add(default)
-    if op == "flash_attention":
-        for bq in FLASH_BLOCK_GRID:
-            for bk in FLASH_BLOCK_GRID:
-                add({"block_q": min(bq, layout.round_up(n, pol.row_align)),
-                     "block_k": min(bk, layout.round_up(k, pol.row_align))})
-    else:
-        for b in ROW_BLOCK_GRID:
-            add({"block_n": pol.block_for(n, b)})
+    for b in ROW_BLOCK_GRID:
+        add({"block_n": pol.block_for(n, b)})
     return cands
 
 
@@ -290,11 +276,6 @@ def _op_args(op: str, *, n: int, k: int, d: int, seed: int = 0) -> tuple:
                 jnp.asarray((rng.random((k, d)) + 0.5).astype(np.float32)),
                 jnp.asarray(np.log(np.full((k,), 1.0 / k,
                                            dtype=np.float32))))
-    if op == "flash_attention":
-        shape = (1, _FLASH_HEADS, n, d)
-        kv = (1, _FLASH_HEADS, k, d)
-        return tuple(jnp.asarray(rng.normal(0, 1, s).astype(np.float32))
-                     for s in (shape, kv, kv))
     raise ValueError(f"unknown autotune op {op!r} "
                      f"(supported: {SUPPORTED_OPS})")
 
@@ -309,10 +290,8 @@ def make_op_call(op: str, backend: str, *, n: int, k: int, d: int,
     args = _op_args(op, n=n, k=k, d=d, seed=seed)
     if op == "kmeans_assign":
         from repro.kernels.kmeans_assign.ops import kmeans_assign as fn
-    elif op == "gmm_estep":
-        from repro.kernels.gmm_estep.ops import gmm_estep as fn
     else:
-        from repro.kernels.flash_attention.ops import flash_attention as fn
+        from repro.kernels.gmm_estep.ops import gmm_estep as fn
 
     def factory(blocks: dict):
         return lambda: fn(*args, backend=backend, **blocks)
@@ -331,16 +310,10 @@ def analytic_cost(op: str, *, n: int, k: int, d: int):
         from repro.kernels.kmeans_assign.ops import kmeans_assign
         fn = functools.partial(kmeans_assign, backend="xla")
         args = (f32((n, d)), f32((k, d)))
-    elif op == "gmm_estep":
+    else:
         from repro.kernels.gmm_estep.ops import gmm_estep
         fn = functools.partial(gmm_estep, backend="xla")
         args = (f32((n, d)), f32((k, d)), f32((k, d)), f32((k,)))
-    else:
-        from repro.kernels.flash_attention.ops import flash_attention
-        fn = functools.partial(flash_attention, backend="xla")
-        q = f32((1, _FLASH_HEADS, n, d))
-        kv = f32((1, _FLASH_HEADS, k, d))
-        args = (q, kv, kv)
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     return analyze(hlo)
 
@@ -435,7 +408,6 @@ def roofline_point(flops: float, bytes_: float, median_s: float,
 _OP_MODULES = {
     "kmeans_assign": "repro.kernels.kmeans_assign.ops",
     "gmm_estep": "repro.kernels.gmm_estep.ops",
-    "flash_attention": "repro.kernels.flash_attention.ops",
 }
 
 

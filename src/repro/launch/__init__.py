@@ -1,3 +1,1 @@
-# NOTE: do not import dryrun here — it sets XLA_FLAGS at import time and must
-# only be imported as the entry module of a fresh process.
-from .mesh import make_production_mesh, make_host_mesh
+from .mesh import make_host_mesh

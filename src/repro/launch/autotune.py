@@ -7,8 +7,7 @@ the winner cache::
         --ops kmeans_assign,gmm_estep --backends interpret,xla \
         --shapes 16384x8x16,65536x8x4 --out autotune_cache.json
 
-Shapes are ``NxKxD`` triples — rows × clusters × features for the
-clustering ops, Sq × Skv × head_dim for ``flash_attention`` — applied to
+Shapes are ``NxKxD`` triples — rows × clusters × features — applied to
 every selected op.  The cache is versioned JSON
 (``repro.kernels.autotune.AutotuneCache``); point
 ``REPRO_AUTOTUNE_CACHE`` (or ``autotune.set_default_cache``) at it and
@@ -35,9 +34,8 @@ def _parse_args(argv=None):
                     help="comma-separated backends (default: interpret + "
                          "xla, plus tpu/gpu when the hardware is present)")
     ap.add_argument("--shapes", default=None,
-                    help="comma-separated NxKxD triples (clustering: rows x "
-                         "clusters x features; flash_attention: Sq x Skv x "
-                         "head_dim); default: per-op suite")
+                    help="comma-separated NxKxD triples (rows x clusters x "
+                         "features); default: per-op suite")
     ap.add_argument("--reps", type=int, default=5,
                     help="timed reps per candidate (median-of-k; default 5)")
     ap.add_argument("--warmup", type=int, default=1,

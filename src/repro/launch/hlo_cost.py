@@ -1,11 +1,10 @@
 """Trip-count-aware HLO cost model.
 
-``compiled.cost_analysis()`` counts every computation ONCE — a scan-over-48-
-layers body, the microbatch accumulation loop and the chunked-attention
-loops are all under-counted by their trip counts, which makes the naive
-numbers useless for a roofline.  The analyzer re-derives per-chip FLOPs,
-HBM bytes and collective bytes from the optimized HLO text with loop
-multiplication:
+``compiled.cost_analysis()`` counts every computation ONCE — a fit's
+sweep loop and its chunk scan are under-counted by their trip counts,
+which makes the naive numbers useless for a roofline.  The analyzer
+re-derives per-chip FLOPs, HBM bytes and collective bytes from the
+optimized HLO text with loop multiplication:
 
   · computations are parsed into op lists (name → result type, opcode,
     operands, attributes);
@@ -23,8 +22,7 @@ multiplication:
     family.
 
 This is a *model* of the per-device program, exact on loop structure,
-approximate on fusion internals — see EXPERIMENTS.md §Roofline for the
-validation against analytic 6·N·D.
+approximate on fusion internals.
 
 The parser and the analyzer itself now live in
 :mod:`repro.analysis.hlo_ir` (promoted in ISSUE 8 so the static-analysis

@@ -1,13 +1,7 @@
-"""Production mesh builders + latency-hiding XLA flag toggles.
+"""The host mesh builder + latency-hiding XLA flag toggles.
 
 A *function*, not a module-level constant — importing this module never
-touches jax device state (the dry-run sets XLA_FLAGS before first init).
-
-Topology (TPU v5e-class target):
-  single-pod: (data=16, model=16)            = 256 chips
-  multi-pod:  (pod=2, data=16, model=16)     = 512 chips
-The design scales by growing "pod" (pure DP across pods — only gradient
-all-reduce crosses the DCN) and "data".
+touches jax device state.
 
 The latency-hiding helpers below wire the async-collective /
 latency-hiding-scheduler XLA flags (SNIPPETS.md snippet 1) into launches
@@ -65,12 +59,6 @@ def overlap_env(env: dict | None = None, enable: bool = True) -> dict:
 
 def _auto(n):
     return (jax.sharding.AxisType.Auto,) * n
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model_axis: int = 1):

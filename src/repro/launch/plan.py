@@ -16,7 +16,7 @@ runner-up table.
 ``--validate`` then executes the chosen plan through the real fit
 drivers on a held-out group: the early-stopped run, the full-convergence
 reference it is priced against, and a short host-stepped loop wrapped in
-``training.straggler.StragglerMonitor`` so slow-shard evidence rides
+``launch.straggler.StragglerMonitor`` so slow-shard evidence rides
 along.  The predicted-vs-actual record (``benchmarks/run.py --only plan``
 commits it as ``BENCH_plan.json``) is CI-gated.
 
@@ -46,7 +46,7 @@ from repro.core.planner import (IterationModel, PlanError, PlanReport,
                                 PlanSpec, ThroughputModel, plan)
 from repro.data import load as load_data
 from repro.launch import compile_cache
-from repro.training.straggler import StragglerMonitor
+from repro.launch.straggler import StragglerMonitor
 
 EXIT_OK = 0
 EXIT_INFEASIBLE = 2

@@ -1,10 +1,10 @@
-"""Host-side straggler watchdog (DESIGN.md §4).
+"""Host-side straggler watchdog for the planner's ``--validate`` run.
 
-At thousand-node scale a single slow host throttles every synchronous step.
-The monitor keeps a rolling window of per-step wall times; a step is flagged
-when it exceeds ``factor`` × the window median (p95-style heuristics are too
-jumpy at small windows).  Flags feed the launcher's retry/requeue policy; in
-this repo they surface in train logs + the Trainer's report.
+A single slow host throttles every synchronous step.  The monitor keeps a
+rolling window of per-step wall times; a step is flagged when it exceeds
+``factor`` × the window median (p95-style heuristics are too jumpy at small
+windows).  ``launch/plan.py`` times a short host-stepped fit loop with it
+and puts :meth:`StragglerMonitor.report` in the validation record.
 """
 from __future__ import annotations
 

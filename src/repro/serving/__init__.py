@@ -1,3 +1,2 @@
-from .serve_loop import Server, Request
 from .batching import AssignRequest, FitRequest, ServeMetrics, pack_batches
 from .cluster_server import ClusterServer, ModelRegistry

@@ -1,17 +1,17 @@
 """Request batching for the cluster assignment server.
 
-The LM server (``serve_loop``) holds its batch shape fixed with slots; the
-assignment server holds it fixed with *buckets*: drained request batches
-are packed greedily (arrival order) up to the largest bucket, padded to the
-smallest bucket that holds them (``kernels.layout.bucket_for``), and the
-padding rows are absorbed by the ops' mask operand.  XLA therefore
+The assignment server holds its batch shape fixed with *buckets*: drained
+request batches are packed greedily (arrival order) up to the largest
+bucket, padded to the smallest bucket that holds them
+(``kernels.layout.bucket_for``), and the padding rows are absorbed by the
+ops' mask operand.  XLA therefore
 compiles one program per (model, bucket) — the recompile-count claim
 ``BENCH_serve_cluster.json`` tracks.
 
-Admission mirrors the LM server's contract (``Server.admit_check``): a
-malformed request raises ``ValueError`` naming the offender *before* any
-device work — empty batches, wrong feature width, unknown model keys and
-batches larger than the largest bucket never enter the queue.
+Admission is strict: a malformed request raises ``ValueError`` naming the
+offender *before* any device work — empty batches, wrong feature width,
+unknown model keys and batches larger than the largest bucket never enter
+the queue.
 """
 from __future__ import annotations
 

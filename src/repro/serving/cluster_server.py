@@ -2,8 +2,7 @@
 
 This is the high-traffic side of the paper's economics (§5.4): models are
 fitted rarely (``launch/cluster.py``), then *applied* constantly.  The
-server generalises ``serve_loop``'s slot discipline — keep the compiled
-shape set closed, refill from a queue — to clustering workloads:
+server keeps the compiled shape set closed and refills it from a queue:
 
   · :class:`ModelRegistry` admits fitted ``(params, LongTailModel)``
     artifacts (``core.artifacts.ClusterArtifact``), keyed by the
@@ -24,9 +23,11 @@ shape set closed, refill from a queue — to clustering workloads:
     padding — padded rows are labelled −1 and dropped before the response
     is split back per request.
 
-Request admission mirrors ``serve_loop.Server.admit_check``: malformed
-batches (empty, wrong feature width, larger than the largest bucket,
-unknown model, duplicate rid) raise ``ValueError`` before any device work.
+Request admission is checked in :meth:`ClusterServer.submit`, before the
+request is queued: an unknown request type raises ``TypeError``; an
+unknown model, an empty batch, a wrong feature width, a batch larger than
+the largest bucket or a rid already pending raises ``ValueError``.  None of
+them does any device work.
 """
 from __future__ import annotations
 
@@ -151,7 +152,7 @@ class ClusterServer:
         self._pending_rids: set = set()
         self.metrics = ServeMetrics()
 
-    # ---- admission (serve_loop.Server.admit_check's contract) ------------
+    # ---- admission -------------------------------------------------------
     def submit(self, req) -> None:
         if not isinstance(req, (AssignRequest, FitRequest)):
             raise TypeError(f"unknown request type {type(req).__name__}")

@@ -46,16 +46,16 @@ def test_cache_json_round_trip(tmp_path):
     cache = autotune.AutotuneCache()
     cache.put("kmeans_assign", "interpret", n=4096, k=8, d=16,
               blocks={"block_n": 512}, median_s=0.001)
-    cache.put("flash_attention", "interpret", n=512, k=512, d=64,
-              blocks={"block_q": 64, "block_k": 128})
+    cache.put("gmm_estep", "interpret", n=512, k=4, d=8,
+              blocks={"block_n": 256})
     path = tmp_path / "cache.json"
     cache.save(str(path))
     loaded = autotune.AutotuneCache.load(str(path))
     assert loaded.entries == cache.entries
     assert loaded.lookup("kmeans_assign", "interpret",
                          n=4096, k=8, d=16) == {"block_n": 512}
-    assert loaded.lookup("flash_attention", "interpret", n=512, k=512,
-                         d=64) == {"block_q": 64, "block_k": 128}
+    assert loaded.lookup("gmm_estep", "interpret", n=512, k=4,
+                         d=8) == {"block_n": 256}
     # the n key is bucketed: any n padding to the same bucket hits
     assert loaded.lookup("kmeans_assign", "interpret",
                          n=2000, k=8, d=16) == {"block_n": 512}
@@ -209,11 +209,14 @@ def test_candidate_grids_respect_backend_policy():
                                           n=4096, k=8, d=16):
         bn = cand["block_n"]
         assert bn & (bn - 1) == 0, cand
-    # flash: pairs capped to the aligned sequence lengths, default first
-    fl = autotune.candidate_blocks("flash_attention", "interpret",
-                                   n=128, k=512, d=64)
-    assert fl[0] == {"block_q": 128, "block_k": 128}
-    assert all(c["block_q"] <= 128 for c in fl)
+    # a small n caps every row block to the aligned row count, default
+    # first, duplicates removed
+    gm = autotune.candidate_blocks("gmm_estep", "interpret",
+                                   n=128, k=4, d=8)
+    assert gm[0] == autotune.default_blocks("gmm_estep", "interpret",
+                                            n=128, k=4, d=8)
+    assert all(c["block_n"] <= 128 for c in gm)
+    assert len({c["block_n"] for c in gm}) == len(gm)
 
 
 def test_roofline_point_geometry():

@@ -112,3 +112,59 @@ def test_long_tail_exists_on_paper_like_data():
     # accuracy at 50% of iterations should already be ≥ 95%
     mid = res["n_iters"] // 2
     assert float(r[mid]) > 0.95
+
+
+def _em_host_estep(x, w, params):
+    """float64 E-step: log densities [N, K], weighted responsibilities
+    [N, K], and their mass, first and second moments and log-likelihood."""
+    x = np.asarray(x, np.float64)
+    mu = np.asarray(params.means, np.float64)
+    var = np.asarray(params.var, np.float64)
+    log_w = np.asarray(params.log_w, np.float64)
+    quad = (((x[:, None, :] - mu[None]) ** 2) / var[None]).sum(-1)
+    lp = log_w[None] - 0.5 * (quad + np.log(var).sum(-1)[None]
+                              + x.shape[1] * np.log(2 * np.pi))
+    top = lp.max(1)
+    lse = top + np.log(np.exp(lp - top[:, None]).sum(1))
+    resp = np.exp(lp - lse[:, None]) * w[:, None]
+    return lp, resp, resp.sum(0), resp.T @ x, resp.T @ (x * x), (lse * w).sum()
+
+
+@pytest.mark.parametrize("d", [1, 3, 11])
+@pytest.mark.parametrize("k", [1, 2, 6, 10])
+def test_em_chunk_stats_match_float64(k, d):
+    """The engine's masked EM chunk pass (``EMAlgorithm.chunk_stats``)
+    against a float64 E-step on the host; rows with mask 0 add nothing."""
+    from repro.core.engine import EMAlgorithm
+    rng = np.random.default_rng(10 * k + d)
+    n = 2000
+    mu = rng.uniform(-3, 3, (k, d)).astype(np.float32)
+    var = rng.uniform(0.5, 2.0, (k, d)).astype(np.float32)
+    log_w = np.log(rng.dirichlet(np.ones(k))).astype(np.float32)
+    z = rng.integers(0, k, n)
+    x = (mu[z] + rng.normal(0, 1, (n, d)) * np.sqrt(var[z])).astype(np.float32)
+    w = np.ones(n, np.float32)
+    w[::5] = 0.0                             # masked rows inside the set
+    w[-100:] = 0.0                           # and a padded tail
+    x[-100:] = 1e3                           # that would dominate any sum
+    params = em_gmm.GMMParams(jnp.asarray(mu), jnp.asarray(var),
+                              jnp.asarray(log_w))
+    labels, (r_sum, r_x, r_x2, loglik) = EMAlgorithm().chunk_stats(
+        jnp.asarray(x), jnp.asarray(w), params)
+    lp, resp, want_sum, want_x, want_x2, want_ll = _em_host_estep(x, w,
+                                                                  params)
+    labels = np.asarray(labels)
+    assert np.all((labels == -1) == (w == 0))
+    live = w > 0
+    rows = np.flatnonzero(live)
+    # the most responsible component; a float32 tie may take either one
+    gap = lp[rows].max(1) - lp[rows, labels[live]]
+    assert np.all(gap <= 1e-5 * (1.0 + np.abs(lp[rows].max(1))))
+    # float32 against float64, relative to the sums of the terms' sizes
+    # (largest reading 1.3e-6)
+    rtol = 1e-5
+    abs_x = resp.T @ np.abs(np.asarray(x, np.float64))
+    assert np.all(np.abs(np.asarray(r_sum) - want_sum) <= rtol * want_sum)
+    assert np.all(np.abs(np.asarray(r_x) - want_x) <= rtol * abs_x)
+    assert np.all(np.abs(np.asarray(r_x2) - want_x2) <= rtol * want_x2)
+    assert abs(float(loglik) - want_ll) <= rtol * abs(want_ll)

@@ -18,7 +18,6 @@ from repro.kernels.kmeans_assign import ops as kops
 from repro.kernels.kmeans_assign.ref import kmeans_assign_ref
 from repro.kernels.gmm_estep import ops as gops
 from repro.kernels.gmm_estep.ref import gmm_estep_ref
-from repro.kernels.flash_attention import ops as fops  # noqa: F401  (registers)
 
 K = 4
 
@@ -43,9 +42,10 @@ def test_registry_lists_all_ops_and_backends():
     ops = dispatch.registered_ops()
     for name in ("kmeans_assign", "gmm_estep"):
         assert set(dispatch.KNOWN_BACKENDS) <= set(ops[name]), ops
-    # flash_attention deliberately has no gpu registration (sequential-grid
-    # online softmax — see test_flash_attention_has_no_gpu_backend)
-    assert {"tpu", "interpret", "xla"} <= set(ops["flash_attention"]), ops
+    # every registered name resolves to that backend's own implementation
+    op = dispatch.get_op("gmm_estep")
+    for b in dispatch.KNOWN_BACKENDS:
+        assert op.impl(b)[0] == b
 
 
 def test_default_backend_resolution():
@@ -266,17 +266,6 @@ def test_gpu_split_reduction_grid_gmm_matches_reference():
                                rtol=2e-4, atol=2e-2)
     np.testing.assert_allclose(jnp.sum(rx2, 1)[0, :k, :d], o2[4],
                                rtol=2e-4, atol=2e-1)
-
-
-def test_flash_attention_has_no_gpu_backend():
-    """The flash kernel's online-softmax scratch assumes a sequential kv
-    grid axis (TPU); a Triton registration would race across CTAs — ensure
-    it stays unregistered (fails loud on GPU hosts) until a split-softmax
-    variant exists."""
-    op = dispatch.get_op("flash_attention")
-    assert "gpu" not in op.backends()
-    with pytest.raises(NotImplementedError, match="no 'gpu' backend"):
-        op.impl("gpu")
 
 
 # --------------------------------------------------------------------------

@@ -1,10 +1,10 @@
-"""LongTail controller, sampling strategy, cloud cost model (Eq. 6/9/10)."""
+"""LongTail model, sampling strategy, cloud cost model (Eq. 6/9/10)."""
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (LongTailModel, EarlyStopHook, fit_longtail,
-                        kfold_split, random_groups, make_grouped, report,
+from repro.core import (LongTailModel, fit_longtail, kfold_split,
+                        random_groups, make_grouped, report,
                         landuse_case_study)
 from repro.core.cost_model import n_images_for_area, CALIFORNIA_AREA_KM2
 
@@ -35,28 +35,6 @@ def test_threshold_ordering_matches_paper_table2():
     hs = [m.threshold_for(a) for a in (0.90, 0.95, 0.99, 0.999)]
     assert hs == sorted(hs, reverse=True)
     assert hs[0] / hs[2] > 10           # orders of magnitude apart (Table 2)
-
-
-def test_earlystop_hook_stops_on_plateau():
-    m = _model(h_at_99=1e-3)
-    hook = EarlyStopHook(m, desired_accuracy=0.99, ema=0.5, patience=3,
-                         min_steps=5)
-    # steeply improving → no stop; plateau → stop
-    stopped_at = None
-    obj = 10.0
-    for step in range(200):
-        obj = obj * (0.7 if step < 20 else 0.999999)
-        if hook.update(obj):
-            stopped_at = step
-            break
-    assert stopped_at is not None and stopped_at > 20
-
-
-def test_earlystop_hook_respects_min_steps():
-    m = _model()
-    hook = EarlyStopHook(m, 0.9, min_steps=50, patience=1)
-    for step in range(49):
-        assert not hook.update(1.0)     # constant loss = h 0, but min_steps
 
 
 @given(st.integers(10, 97), st.integers(2, 10))

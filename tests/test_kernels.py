@@ -11,8 +11,6 @@ from repro.kernels.kmeans_assign.ops import kmeans_assign_chunked
 from repro.kernels.kmeans_assign.ref import kmeans_assign_ref
 from repro.kernels.gmm_estep import gmm_estep
 from repro.kernels.gmm_estep.ref import gmm_estep_ref
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
 
 RNG = np.random.default_rng(0)
 
@@ -74,48 +72,6 @@ def test_gmm_estep_responsibilities_sum():
     assert float(jnp.sum(r_sum)) == pytest.approx(n, rel=1e-4)
 
 
-@pytest.mark.parametrize("b,hq,hkv,s,dh,causal,win,dtype", [
-    (2, 4, 2, 256, 64, True, None, jnp.float32),
-    (1, 8, 8, 128, 64, False, None, jnp.float32),
-    (2, 4, 1, 200, 80, True, None, jnp.float32),
-    (1, 4, 2, 256, 64, True, 64, jnp.float32),
-    (1, 2, 2, 96, 128, True, None, jnp.float32),
-    (1, 4, 2, 128, 64, True, None, jnp.bfloat16),
-])
-def test_flash_attention_sweep(b, hq, hkv, s, dh, causal, win, dtype):
-    q = jnp.asarray(RNG.normal(0, 1, (b, hq, s, dh)), dtype)
-    k = jnp.asarray(RNG.normal(0, 1, (b, hkv, s, dh)), dtype)
-    v = jnp.asarray(RNG.normal(0, 1, (b, hkv, s, dh)), dtype)
-    o1 = flash_attention(q, k, v, causal=causal, window=win)
-    o2 = attention_ref(q, k, v, causal=causal, window=win)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    assert float(jnp.max(jnp.abs(o1.astype(jnp.float32)
-                                 - o2.astype(jnp.float32)))) < tol
-
-
-@given(s=st.integers(16, 200), dh=st.sampled_from([32, 64]),
-       win=st.one_of(st.none(), st.integers(8, 64)))
-@settings(max_examples=10, deadline=None)
-def test_flash_attention_property(s, dh, win):
-    rng = np.random.default_rng(s * 13 + dh)
-    q = jnp.asarray(rng.normal(0, 1, (1, 4, s, dh)).astype(np.float32))
-    k = jnp.asarray(rng.normal(0, 1, (1, 2, s, dh)).astype(np.float32))
-    v = jnp.asarray(rng.normal(0, 1, (1, 2, s, dh)).astype(np.float32))
-    o1 = flash_attention(q, k, v, causal=True, window=win)
-    o2 = attention_ref(q, k, v, causal=True, window=win)
-    assert float(jnp.max(jnp.abs(o1 - o2))) < 2e-5
-
-
-def test_flash_attention_xla_backend_is_reference():
-    """The registry's xla backend for flash_attention IS the oracle."""
-    q = jnp.asarray(RNG.normal(0, 1, (1, 4, 96, 32)), jnp.float32)
-    k = jnp.asarray(RNG.normal(0, 1, (1, 2, 96, 32)), jnp.float32)
-    v = jnp.asarray(RNG.normal(0, 1, (1, 2, 96, 32)), jnp.float32)
-    o1 = flash_attention(q, k, v, causal=True, backend="xla")
-    o2 = attention_ref(q, k, v, causal=True)
-    assert float(jnp.max(jnp.abs(o1 - o2))) == 0.0
-
-
 def test_kmeans_assign_chunked_mask_slices_with_chunks():
     """The shared chunked driver slices the mask alongside the rows."""
     x = jnp.asarray(RNG.normal(0, 5, (300, 4)).astype(np.float32))
@@ -126,16 +82,3 @@ def test_kmeans_assign_chunked_mask_slices_with_chunks():
     assert (a[0] == b[0]).all()
     np.testing.assert_allclose(a[1], b[1], rtol=1e-5, atol=1e-3)
     np.testing.assert_allclose(a[3], b[3], rtol=1e-5)
-
-
-def test_chunked_jnp_attention_matches_exact():
-    from repro.models.layers import _sdpa, _sdpa_chunked
-    rng = np.random.default_rng(5)
-    q = jnp.asarray(rng.normal(0, 1, (2, 300, 4, 32)).astype(np.float32))
-    k = jnp.asarray(rng.normal(0, 1, (2, 300, 2, 32)).astype(np.float32))
-    v = jnp.asarray(rng.normal(0, 1, (2, 300, 2, 32)).astype(np.float32))
-    for causal, win in [(True, None), (False, None), (True, 64)]:
-        o1 = _sdpa_chunked(q, k, v, causal=causal, window=win,
-                           block_q=64, block_k=128)
-        o2 = _sdpa(q, k, v, causal=causal, window=win)
-        assert float(jnp.max(jnp.abs(o1 - o2))) < 3e-5

@@ -121,6 +121,24 @@ def test_assign_and_stats_matches_float64_and_scatter_oracle(case):
             _check(x, w, cs[i], tuple(a[i] for a in out))
 
 
+@pytest.mark.parametrize("d", [1, 3, 11])
+@pytest.mark.parametrize("k", [1, 2, 6, 10, 64, 256, 1024])
+def test_assign_and_stats_k_sweep(k, d):
+    """The contraction at every K and D the chip's K sweep read (K up to
+    1024, where the one-hot is the widest), with masked rows."""
+    n = 3000
+    x = _points(n, d, seed=k + d)
+    w = np.ones(n, np.float32)
+    w[::7] = 0.0                             # masked rows inside the set
+    w[-200:] = 0.0                           # and a padded tail
+    x[-200:] = 1e4
+    c = jnp.asarray(x[: n - 200 : (n - 200) // k][:k])
+    labels, sums, counts = _check(x, w, c, km.assign_and_stats(
+        jnp.asarray(x), c, mask=jnp.asarray(w)))
+    assert np.all((labels == -1) == (w == 0))
+    assert counts.sum() == w.sum()
+
+
 # (cell, N, D, K): the benchmark's one-chunk k-means sweeps at small N
 SWEEP_SHAPES = [("poker", 2048, 11, 10), ("landuse", 2048, 3, 6)]
 

@@ -1,6 +1,7 @@
 """Planner tests (ISSUE 10): pricing edge cases, spot/on-demand crossover,
-throughput interpolation off-grid, iteration-model behaviour, and the
-predicted-vs-actual validation loop on the small skin config."""
+throughput interpolation off-grid, iteration-model behaviour, the
+predicted-vs-actual validation loop on the small skin config, and the
+straggler monitor that loop is timed with."""
 from __future__ import annotations
 
 import dataclasses
@@ -409,3 +410,17 @@ def test_validate_small_skin_config():
     assert record["predicted"]["cost_usd"] > 0
     assert record["actual"]["cost_usd"] > 0
     assert record["full_actual"]["iters"] >= 1
+
+
+def test_straggler_monitor_flags_slow_steps():
+    import time
+    from repro.launch.straggler import StragglerMonitor
+    m = StragglerMonitor(window=20, factor=2.0, grace_steps=2)
+    for i in range(15):
+        m.start()
+        time.sleep(0.012 if i == 12 else 0.001)
+        flagged = m.stop()
+        if i == 12:
+            assert flagged
+    rep = m.report()
+    assert rep["flagged"] >= 1 and rep["steps"] == 15

@@ -74,3 +74,32 @@ def test_lasso_close_to_ols_with_tiny_penalty(seed):
     assert np.allclose(np.asarray(ols.predict(grid)),
                        np.asarray(lasso.predict(grid)),
                        atol=0.05 * scale + 1e-3)
+
+
+# one member of each family, decreasing on [0, 1] (the shape of h(r))
+_R = np.linspace(0.0, 1.0, 201)
+FAMILY_CURVES = {
+    "linear": 0.5 - 0.2 * _R,
+    "quadratic": 1.0 - 1.2 * _R + 0.3 * _R ** 2,
+    "cubic": 1.0 - 1.5 * _R + 0.9 * _R ** 2 - 0.3 * _R ** 3,
+    "exponential": 0.5 * np.exp(-6.0 * _R),
+    "lasso_quadratic": 1.0 - 1.2 * _R + 0.3 * _R ** 2,
+    "log_quadratic": np.exp(-1.0 - 4.0 * _R - _R ** 2),
+}
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_family_fits_its_own_curve(family):
+    m = fit_family(_R, FAMILY_CURVES[family], family)
+    assert m.family == family
+    assert m.metrics.r2 > 0.999, m.metrics
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_threshold_for_is_the_prediction_on_a_decreasing_curve(family):
+    """On a curve that decreases up to r*, h* = f(r*): the running-min
+    guard only bites past a vertex, and its grid ends at r* itself."""
+    from repro.core.regression import H_STAR_FLOOR
+    m = fit_family(_R, FAMILY_CURVES[family], family)
+    want = max(float(m.predict(0.99)), H_STAR_FLOOR)
+    assert m.threshold_for(0.99) == pytest.approx(want, rel=1e-6)

@@ -1,7 +1,7 @@
 """End-to-end behaviour of the paper's system: sample → fit regression →
-early stop → accuracy/cost validation; plus the LM-loop generalisation and
-the distributed clustering path (in-process 8-device session; only the CLI
-smoke tests still spawn subprocesses — they test the CLI itself)."""
+early stop → accuracy/cost validation; plus the distributed clustering path
+(in-process 8-device session; only the CLI smoke tests still spawn
+subprocesses — they test the CLI itself)."""
 import json
 import subprocess
 import sys
@@ -58,44 +58,81 @@ def test_spacenet_image_groups():
     assert acc > 0.9
 
 
-def test_lm_longtail_generalisation():
-    """Beyond-paper: the controller stops LM training near a target fraction
-    of final quality (pilot run fits the regression, main run early-stops)."""
-    from repro.configs import get_config
-    from repro.training import Trainer, TrainConfig, OptimizerConfig
+# run_production's regimes: (mode, chunks, batch_chunks)
+PROD_MODES = {"full": (1, 0), "minibatch": (8, 2)}
 
-    cfg = get_config("qwen3-8b", reduced=True)
-    tc = TrainConfig(opt=OptimizerConfig(peak_lr=5e-3, warmup_steps=5,
-                                         total_steps=120))
 
-    def data():
-        rng = np.random.default_rng(7)
-        while True:
-            start = rng.integers(0, cfg.vocab, size=(4, 1))
-            yield {"tokens": jnp.asarray((start + np.arange(32)) % cfg.vocab,
-                                         jnp.int32)}
+def _prod_data():
+    return load("skin", n=4000, seed=2)
 
-    # pilot: run to (near-)convergence, harvest (r, h) from the loss curve
-    pilot = Trainer(cfg, tc, data(), seed=1)
-    pilot.run(100)
-    losses = np.array([m["loss"] for m in pilot.metrics_log])
-    final, first = losses[-5:].mean(), losses[:3].mean()
-    # quality proxy r_i = relative progress toward final loss
-    sm = np.convolve(losses, np.ones(5) / 5, mode="valid")
-    r = np.clip((first - sm) / max(first - final, 1e-9), 0, 1)
-    h = np.abs(np.diff(sm)) / np.maximum(np.abs(sm[:-1]), 1e-9)
-    model = core.fit_longtail([(r[1:], h)], algorithm="lm_train",
-                              dataset="markov", family="quadratic")
-    hook = core.EarlyStopHook(model, desired_accuracy=0.95, ema=0.8,
-                              patience=5, min_steps=20)
-    main = Trainer(cfg, tc, data(), earlystop=hook, seed=1)
-    rep = main.run(100)
-    if rep["stopped_early"]:
-        assert rep["final_step"] < 100
-        stopped_loss = main.metrics_log[-1]["loss"]
-        # must have realised most of the achievable improvement
-        progress = (first - stopped_loss) / max(first - final, 1e-9)
-        assert progress > 0.6, progress
+
+def _engine_direct(x, k, algorithm, h_star, mode, restarts, seed):
+    """The job ``run_production`` runs, built from the engine alone: the
+    same k-means++ seeding (k-means++-seeded GMMs for EM) and the same
+    stop settings, called through ``ClusteringEngine`` directly."""
+    from repro.core import em_gmm
+    from repro.core.engine import ClusteringEngine, EngineConfig
+    chunks, batch_chunks = PROD_MODES[mode]
+    mb = dict(seed=seed) if mode == "minibatch" else {}
+    eng = ClusteringEngine(algorithm, EngineConfig(
+        max_iters=80, h_star=h_star, patience=3, chunks=chunks,
+        stop_when_frozen=algorithm == "kmeans", mode=mode,
+        batch_chunks=batch_chunks, **mb))
+    x = jnp.asarray(x)
+    key = jax.random.PRNGKey(seed)
+    if restarts > 1 and algorithm == "em":
+        inits = [em_gmm.init_from_kmeans(
+            x, core.kmeans_plus_plus_init(kk, x, k, chunks=chunks))
+            for kk in jax.random.split(key, restarts)]
+        params0 = jax.tree.map(lambda *ls: jnp.stack(ls), *inits)
+        return eng.fit_restarts(x, params0).best
+    if restarts > 1:
+        return eng.fit_restarts(x, eng.init_restarts(key, x, k,
+                                                     restarts)).best
+    c0 = core.kmeans_plus_plus_init(key, x, k, chunks=chunks)
+    return eng.fit(x, c0 if algorithm == "kmeans"
+                   else em_gmm.init_from_kmeans(x, c0))
+
+
+@pytest.mark.parametrize("restarts", [1, 4])
+@pytest.mark.parametrize("mode", list(PROD_MODES))
+@pytest.mark.parametrize("algorithm", ["kmeans", "em"])
+def test_run_production_matches_engine(algorithm, mode, restarts):
+    """The benchmark's job (``run_production``) returns what the engine
+    gives for the same seeding and h*: labels, objective, iterations."""
+    x = _prod_data()
+    chunks, batch_chunks = PROD_MODES[mode]
+    labels, objective, iters, _ = run_production(
+        x, 2, algorithm, 1e-4, max_iters=80, seed=3, chunks=chunks,
+        mode=mode, batch_chunks=batch_chunks, restarts=restarts)
+    want = _engine_direct(x, 2, algorithm, 1e-4, mode, restarts, seed=3)
+    np.testing.assert_array_equal(np.asarray(labels),
+                                  np.asarray(want.labels))
+    assert objective == float(want.objective)
+    assert iters == int(want.n_iters)
+
+
+@pytest.mark.parametrize("mode", list(PROD_MODES))
+@pytest.mark.parametrize("algorithm", ["kmeans", "em"])
+def test_run_production_model_path_equals_h_star_path(algorithm, mode):
+    """A job handed the stop model and r* runs exactly the job handed
+    h* = f(r*): the model path derives nothing else."""
+    r = np.linspace(0.5, 1.0, 60)
+    model = core.fit_longtail([(r, 10.0 * (1.0 - r) ** 2)],
+                              algorithm=algorithm, dataset="skin",
+                              family="quadratic")
+    h_star = model.threshold_for(0.99)
+    assert h_star > 1e-4                 # the stop is live, not the floor
+    x = _prod_data()
+    chunks, batch_chunks = PROD_MODES[mode]
+    kw = dict(max_iters=80, seed=3, chunks=chunks, mode=mode,
+              batch_chunks=batch_chunks)
+    by_model = run_production(x, 2, algorithm, 0.0, model=model,
+                              desired_accuracy=0.99, **kw)
+    by_h = run_production(x, 2, algorithm, h_star, **kw)
+    np.testing.assert_array_equal(np.asarray(by_model[0]),
+                                  np.asarray(by_h[0]))
+    assert by_model[1:3] == by_h[1:3]
 
 
 def test_distributed_clustering_matches_single_device(mesh8):
@@ -263,16 +300,3 @@ def test_sharded_compressed_production(mesh8):
                                    prefetch=True, **kw)
     assert abs(int(i1) - int(i2)) <= 1, (i1, i2)
     assert float(core.rand_index(l1, l2, 2, 2)) > 0.999
-
-
-def test_train_cli_smoke(tmp_path):
-    r = subprocess.run(
-        [sys.executable, "-m", "repro.launch.train", "--arch", "qwen3-8b",
-         "--steps", "8", "--batch", "2", "--seq", "32",
-         "--ckpt-dir", str(tmp_path / "ck"),
-         "--out", str(tmp_path / "train.json")],
-        capture_output=True, text=True, timeout=600, cwd="/root/repo",
-        env=_cli_env())
-    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
-    rep = json.loads((tmp_path / "train.json").read_text())
-    assert rep["final_step"] == 8
